@@ -7,8 +7,9 @@ Implements the production behaviours of App. B:
     single-tier ``CacheStore`` or a multi-tier ``TieredCacheStore``
     (``repro.core.cache``) — both expose the same offer/get surface
   * controller auto-retry with backoff on the known transient patterns
-  * straggler mitigation: a speculative duplicate races any step exceeding
-    ``straggler_factor x est_time_s`` when spare workers exist
+  * straggler mitigation: a speculative duplicate races any host step
+    exceeding ``straggler_factor x est_time_s`` when spare workers exist;
+    device steps are never raced (see ``_is_device_step``)
   * big-workflow auto-split (Algorithm 3) before scheduling
   * restart-from-failure: ``resume(run)`` skips Succeeded/Skipped/Cached
 
@@ -29,6 +30,7 @@ import concurrent.futures as cf
 import hashlib
 import itertools
 import pickle
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -650,7 +652,7 @@ class LocalEngine(Engine):
         if self.profile_steps:
             return self._profiled_invoke(job, run, args)
 
-        if not self.enable_speculation:
+        if not self.enable_speculation or _is_device_step(job, args):
             return job.fn(*args, **job.kwargs)
 
         # straggler mitigation: race a speculative copy if the primary
@@ -704,33 +706,21 @@ class LocalEngine(Engine):
 
     def _profiled_invoke(self, job: Job, run: WorkflowRun, args: List[Any]):
         """Invoke with compute-layer profiling (``profile_steps=True``):
-        when the fn supports jax AOT (``fn.lower(...).compile()``) the
-        compile and execute phases are timed separately; otherwise the
-        plain call is timed whole. Only lower/compile failures fall back —
-        an exception from the *compiled* call propagates (re-running via
-        the plain path would double-execute user code). The profile lands
-        on ``StepRecord.profile``; the gateway folds it into histograms
-        and span annotations."""
+        when the fn is jitted (``fn.lower(...).compile()``) the compile and
+        execute phases are timed separately; otherwise the plain call is
+        timed whole. A lower or compile error propagates like any other
+        step error. The profile lands on ``StepRecord.profile``; the
+        gateway folds it into histograms and span annotations."""
         fn = job.fn
         prof: Dict[str, float] = {}
-        compiled = None
         if hasattr(fn, "lower"):
             t0 = time.time()
-            try:
-                compiled = fn.lower(*args, **job.kwargs).compile()
-                prof["compile_s"] = time.time() - t0
-            except Exception:   # noqa: BLE001 — not AOT-able: plain call
-                compiled = None
-        if compiled is not None:
-            t1 = time.time()
-            value = compiled(*args, **job.kwargs)
-            _block_until_ready(value)
-            prof["execute_s"] = time.time() - t1
-        else:
-            t1 = time.time()
-            value = fn(*args, **job.kwargs)
-            _block_until_ready(value)
-            prof["execute_s"] = time.time() - t1
+            fn = fn.lower(*args, **job.kwargs).compile()
+            prof["compile_s"] = time.time() - t0
+        t1 = time.time()
+        value = fn(*args, **job.kwargs)
+        _block_until_ready(value)
+        prof["execute_s"] = time.time() - t1
         mem = _device_memory_bytes()
         if mem is not None:
             prof["device_bytes_in_use"] = float(mem)
@@ -738,14 +728,28 @@ class LocalEngine(Engine):
         return value
 
 
+def _is_device_step(job: Job, args: List[Any]) -> bool:
+    """A step that declares an accelerator (``Resources.gpu > 0``) or is
+    handed a jax array runs on the process's device. A speculative copy of
+    it would be a second program on the same chip, competing for its
+    memory and sharing any buffers the step donates, so it is never
+    raced."""
+    if job.resources.gpu > 0:
+        return True
+    jax = sys.modules.get("jax")      # no jax imported: no device arrays
+    if jax is None:
+        return False
+    return any(isinstance(leaf, jax.Array)
+               for leaf in jax.tree_util.tree_leaves((args, job.kwargs)))
+
+
 def _block_until_ready(v: Any) -> None:
-    """Force async jax dispatch to finish so execute_s measures real
-    device time; a no-op for non-jax values."""
-    if hasattr(v, "block_until_ready"):
-        try:
-            v.block_until_ready()
-        except Exception:   # noqa: BLE001 — best-effort timing fence
-            pass
+    """Wait until every jax array in the output pytree is computed, so
+    execute_s measures device time; device errors propagate. A no-op when
+    jax was never imported (no value can hold a device array)."""
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.block_until_ready(v)
 
 
 def _device_memory_bytes() -> Optional[int]:

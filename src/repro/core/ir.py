@@ -44,7 +44,7 @@ import numpy as np
 class Resources:
     cpu: float = 1.0
     mem_bytes: int = 1 << 28
-    gpu: float = 0.0
+    gpu: float = 0.0        # accelerators (GPUs or TPU chips) the job runs on
 
     def as_dict(self):
         return dataclasses.asdict(self)

@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+# Pallas kernels for the TPU, one per module, each compared against its
+# plain-JAX reference in ref.py. They lower through Mosaic by default;
+# interpret=True runs them in the Pallas interpreter (CPU tests).

@@ -81,11 +81,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = True,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q,k,v: (BH, S, D) (v may have different last dim). Returns (BH,S,Dv).
 
-    ``interpret=True`` executes on CPU for validation; on TPU pass False
-    to lower through Mosaic.
+    Lowers through Mosaic for the TPU; ``interpret=True`` runs the kernel
+    body in the Pallas interpreter instead (CPU validation).
     """
     BH, S, D = q.shape
     Dv = v.shape[-1]

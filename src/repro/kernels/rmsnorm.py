@@ -23,7 +23,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "eps", "interpret"))
 def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
-            interpret: bool = True):
+            interpret: bool = False):
     """x: (R, D); scale: (D,). Returns (R, D)."""
     R, D = x.shape
     block_rows = min(block_rows, R)

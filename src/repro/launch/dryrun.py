@@ -27,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_arch, get_shape
 from repro.configs.base import LM_SHAPES
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import cache_specs_shapes, input_specs
 from repro.models import transformer as T
@@ -201,11 +202,10 @@ def main() -> None:
                     choices=[None, "none", "dots", "full"])
     ap.add_argument("--tag", default=None, help="suffix for the output json")
     ap.add_argument("--all", action="store_true")
-    ap.add_argument("--subprocess-per-cell", action="store_true",
-                    help="isolate each cell's compile in a fresh process")
     ap.add_argument("--out", default=str(OUT_DIR))
     args = ap.parse_args()
     out_dir = Path(args.out)
+    use_compile_cache()
 
     if args.all:
         failures = []
@@ -222,28 +222,17 @@ def main() -> None:
             if path.exists() and json.loads(path.read_text()).get("status") == "ok":
                 print(f"[{mesh_tag}] {arch_id} x {shape_name}: cached")
                 continue
-            if args.subprocess_per_cell:
-                import subprocess
-                cmd = [sys.executable, "-m", "repro.launch.dryrun",
-                       "--arch", arch_id, "--shape", shape_name,
-                       "--out", str(out_dir)]
-                if args.multi_pod:
-                    cmd.append("--multi-pod")
-                r = subprocess.run(cmd, timeout=7200)
-                if r.returncode != 0:
-                    failures.append((arch_id, shape_name))
-            else:
-                try:
-                    run_cell(arch_id, shape_name, multi_pod=args.multi_pod,
-                             out_dir=out_dir)
-                except Exception as e:  # noqa: BLE001
-                    failures.append((arch_id, shape_name))
-                    path.parent.mkdir(parents=True, exist_ok=True)
-                    path.write_text(json.dumps(
-                        {"arch": arch_id, "shape": shape_name,
-                         "status": "error", "error": f"{type(e).__name__}: {e}",
-                         "traceback": traceback.format_exc()[-4000:]}, indent=1))
-                    print(f"FAIL {arch_id} x {shape_name}: {type(e).__name__}: {e}")
+            try:
+                run_cell(arch_id, shape_name, multi_pod=args.multi_pod,
+                         out_dir=out_dir)
+            except Exception as e:  # noqa: BLE001
+                failures.append((arch_id, shape_name))
+                path.parent.mkdir(parents=True, exist_ok=True)
+                path.write_text(json.dumps(
+                    {"arch": arch_id, "shape": shape_name,
+                     "status": "error", "error": f"{type(e).__name__}: {e}",
+                     "traceback": traceback.format_exc()[-4000:]}, indent=1))
+                print(f"FAIL {arch_id} x {shape_name}: {type(e).__name__}: {e}")
         if failures:
             print("FAILED CELLS:", failures)
             sys.exit(1)

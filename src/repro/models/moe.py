@@ -26,7 +26,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.sharding.compat import shard_map
 
 from repro.models import layers as L
 from repro.sharding.ctx import axis_ctx, current_strategy, shard
@@ -199,7 +198,7 @@ def _apply_moe_a2a(cfg, mesh, x2d, idx2d, w2d, ex):
         y = jnp.zeros((t + 1, D), out2.dtype).at[s_tok[:-1]].add(out2)
         return y[:-1][None, None]
 
-    y4 = shard_map(
+    y4 = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(rspec, rspec, rspec, gspec, gspec, dspec),
         out_specs=rspec, check_vma=False,
@@ -277,7 +276,7 @@ def apply_moe(p, cfg, x) -> Tuple[jax.Array, jax.Array]:
                                           axis=0, tiled=True).astype(y.dtype)
             return jax.lax.psum(y, "model")
 
-        y = shard_map(
+        y = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(bspec, bspec, bspec, gspec, gspec, dspec),
             out_specs=bspec, check_vma=False,

@@ -97,7 +97,9 @@ def ssd_chunked(xh, dt, a_log, Bm, Cm, chunk: int):
         dA_k, x_k, B_k, C_k = inp                        # (B,Q,H), (B,Q,H,P), (B,Q,H,N)
         cum = jnp.cumsum(dA_k, axis=1)                   # (B,Q,H)
         seg = cum[:, :, None, :] - cum[:, None, :, :]    # (B,Qt,Qs,H)
-        Lmat = jnp.where(tri[None, :, :, None], jnp.exp(seg), 0.0)
+        # mask before exp: above the diagonal seg > 0 and exp overflows at
+        # long chunks, and where(mask, inf, 0) has a NaN gradient
+        Lmat = jnp.exp(jnp.where(tri[None, :, :, None], seg, -jnp.inf))
         Lmat = Lmat.transpose(0, 3, 1, 2)                # (B,H,Qt,Qs)
         CB = jnp.einsum("bthn,bshn->bhts", C_k, B_k)     # (B,H,Qt,Qs)
         y = jnp.einsum("bhts,bshp->bthp", CB * Lmat, x_k)

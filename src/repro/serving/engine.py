@@ -23,6 +23,9 @@ class GenerationResult:
     prefill_s: float
     decode_s: float
     tokens_per_s: float
+    # (B, V) float32 logits at the last prompt position, from which the
+    # first generated token is sampled
+    prompt_logits: Optional[jax.Array] = None
 
 
 class ServingEngine:
@@ -48,7 +51,9 @@ class ServingEngine:
         for i in range(P):                      # prefill via the decode path
             logits, caches = self._step(self.params, prompts[:, i:i + 1],
                                         caches, jnp.int32(i))
+        jax.block_until_ready((logits, caches))
         prefill_s = time.time() - t0
+        prompt_logits = logits[:, -1]
 
         def sample(lg, k):
             if temperature <= 0:
@@ -63,8 +68,9 @@ class ServingEngine:
             key = jax.random.fold_in(key, i)
             tok = sample(logits, key)
             out.append(tok)
+        gen = jax.block_until_ready(jnp.concatenate(out, axis=1))
         decode_s = time.time() - t0
-        gen = jnp.concatenate(out, axis=1)
         return GenerationResult(
             tokens=gen.tolist(), prefill_s=prefill_s, decode_s=decode_s,
-            tokens_per_s=B * gen.shape[1] / max(decode_s, 1e-9))
+            tokens_per_s=B * gen.shape[1] / max(decode_s, 1e-9),
+            prompt_logits=prompt_logits)

@@ -17,7 +17,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from repro.sharding.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -73,9 +72,9 @@ def pipeline_apply(stage_fn: Callable, mesh: Mesh, *, stage_axis: str = "stage",
     def run(stage_params, x):
         in_specs = (jax.tree.map(lambda _: pspec, stage_params),
                     P(stage_axis))
-        y = shard_map(local, mesh=mesh,
-                      in_specs=in_specs, out_specs=P(stage_axis),
-                      check_vma=False)(
+        y = jax.shard_map(local, mesh=mesh,
+                          in_specs=in_specs, out_specs=P(stage_axis),
+                          check_vma=False)(
             stage_params,
             jnp.broadcast_to(x[None], (S,) + x.shape))
         return y[0]

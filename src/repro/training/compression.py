@@ -22,7 +22,6 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from repro.sharding.compat import axis_size, shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -37,7 +36,7 @@ def _dequantize(q: jax.Array, scale: jax.Array, n: int) -> jax.Array:
 
 def compressed_psum_mean(g: jax.Array, axis: str) -> jax.Array:
     """int8 ring all-reduce-mean over ``axis`` (call inside shard_map)."""
-    n = axis_size(axis)
+    n = jax.lax.axis_size(axis)
     flat = g.reshape(-1).astype(jnp.float32)
     pad = (-flat.shape[0]) % n
     flat = jnp.pad(flat, (0, pad))
@@ -97,8 +96,8 @@ def make_compressed_grad_fn(loss_fn, mesh, data_axes=("data",)):
     def wrapped(params, err, batch):
         rep = lambda t: jax.tree.map(lambda _: P(), t)
         bspec = jax.tree.map(lambda _: P(axis), batch)
-        return shard_map(local_grads, mesh=mesh,
-                         in_specs=(rep(params), rep(err), bspec),
-                         out_specs=(P(), rep(params), rep(err)),
-                         check_vma=False)(params, err, batch)
+        return jax.shard_map(local_grads, mesh=mesh,
+                             in_specs=(rep(params), rep(err), bspec),
+                             out_specs=(P(), rep(params), rep(err)),
+                             check_vma=False)(params, err, batch)
     return wrapped

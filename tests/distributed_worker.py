@@ -124,15 +124,14 @@ def check_moe_a2a_matches_local():
 def check_compressed_psum():
     from repro.launch.mesh import make_mesh
     from repro.training.compression import compressed_psum_mean
-    from repro.sharding.compat import shard_map
     mesh = make_mesh((8,), ("data",))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 1000))
 
     def f(gl):
         return compressed_psum_mean(gl[0], "data")[None]
 
-    red = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-                    check_vma=False)(g)
+    red = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                        check_vma=False)(g)
     exact = jnp.mean(g, axis=0)
     rel = float(jnp.max(jnp.abs(red[0] - exact)) / (jnp.max(jnp.abs(exact)) + 1e-9))
     assert rel < 0.05, rel
@@ -145,7 +144,6 @@ def check_compression_wire_bytes():
     from repro.launch.mesh import make_mesh
     from repro.roofline.analysis import analyze_hlo
     from repro.training.compression import compressed_psum_mean
-    from repro.sharding.compat import shard_map
     mesh = make_mesh((8,), ("data",))
     n = 1 << 16
 
@@ -157,8 +155,8 @@ def check_compression_wire_bytes():
 
     sds = jax.ShapeDtypeStruct((8, n), jnp.float32)
     def wire(fn):
-        c = jax.jit(shard_map(fn, mesh=mesh, in_specs=P("data"),
-                              out_specs=P("data"), check_vma=False)
+        c = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("data"),
+                                  out_specs=P("data"), check_vma=False)
                     ).lower(sds).compile()
         return analyze_hlo(c.as_text()).coll_bytes
     wp, wc = wire(plain), wire(comp)

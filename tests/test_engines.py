@@ -111,6 +111,36 @@ def test_straggler_speculation():
     assert time.time() - t0 < 1.0
 
 
+@pytest.mark.parametrize("device", ["declared", "array_input"])
+def test_device_step_never_speculated(device):
+    """A step on the device runs once even when it straggles: a second copy
+    would be a second program on the same chip."""
+    import jax.numpy as jnp
+    calls = {"n": 0}
+
+    def slow_device_step(x=None):
+        calls["n"] += 1
+        time.sleep(0.3)
+        return calls["n"]
+
+    with couler.workflow("device-strag") as ir:
+        if device == "declared":
+            couler.run_step(slow_device_step, step_name="s", est_time_s=0.01,
+                            cacheable=False, resources=Resources(gpu=1))
+        else:
+            couler.run_step(slow_device_step, jnp.ones(4), step_name="s",
+                            est_time_s=0.01, cacheable=False)
+    eng = LocalEngine(straggler_factor=2.0)
+    try:
+        run = eng.submit(ir)
+    finally:
+        eng.close()
+    assert run.succeeded()
+    assert calls["n"] == 1
+    assert run.steps["s"].attempts == 1
+    assert not run.steps["s"].speculative
+
+
 def test_parallelism_actually_parallel():
     barrier = threading.Barrier(4, timeout=5)
 

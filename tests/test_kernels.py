@@ -5,7 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ref
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rmsnorm import rmsnorm
+from repro.kernels.ssd_scan import ssd_scan
 
 
 def _rand(key, shape, dtype):
@@ -26,7 +29,8 @@ def test_flash_attention_sweep(S, D, blocks, dtype):
     q = _rand(key, (2, S, D), dtype)
     k = _rand(jax.random.fold_in(key, 1), (2, S, D), dtype)
     v = _rand(jax.random.fold_in(key, 2), (2, S, D), dtype)
-    o = ops.flash_attention(q, k, v, block_q=blocks[0], block_k=blocks[1])
+    o = flash_attention(q, k, v, block_q=blocks[0], block_k=blocks[1],
+                        interpret=True)
     o_ref = ref.reference_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o_ref, np.float32),
@@ -38,7 +42,7 @@ def test_flash_attention_non_causal():
     q = _rand(key, (1, 128, 32), jnp.float32)
     k = _rand(jax.random.fold_in(key, 1), (1, 128, 32), jnp.float32)
     v = _rand(jax.random.fold_in(key, 2), (1, 128, 32), jnp.float32)
-    o = ops.flash_attention(q, k, v, causal=False)
+    o = flash_attention(q, k, v, causal=False, interpret=True)
     o_ref = ref.reference_attention(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
 
@@ -49,7 +53,7 @@ def test_flash_attention_mixed_v_dim():
     q = _rand(key, (2, 128, 48), jnp.float32)
     k = _rand(jax.random.fold_in(key, 1), (2, 128, 48), jnp.float32)
     v = _rand(jax.random.fold_in(key, 2), (2, 128, 32), jnp.float32)
-    o = ops.flash_attention(q, k, v)
+    o = flash_attention(q, k, v, interpret=True)
     o_ref = ref.reference_attention(q, k, v)
     assert o.shape == (2, 128, 32)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref), atol=2e-5)
@@ -65,7 +69,7 @@ def test_ssd_scan_sweep(S, P, N, chunk, dtype):
         jax.random.fold_in(key, 1), (2, S)))).astype(jnp.float32)
     Bm = (_rand(jax.random.fold_in(key, 2), (2, S, N), dtype) * 0.5).astype(dtype)
     Cm = (_rand(jax.random.fold_in(key, 3), (2, S, N), dtype) * 0.5).astype(dtype)
-    y = ops.ssd_scan(x, dA, Bm, Cm, chunk=chunk)
+    y = ssd_scan(x, dA, Bm, Cm, chunk=chunk, interpret=True)
     y_ref, _ = ref.reference_ssd(x, dA, Bm, Cm)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(y_ref, np.float32),
@@ -79,7 +83,7 @@ def test_rmsnorm_sweep(R, D, br, dtype):
     key = jax.random.PRNGKey(R + D)
     x = _rand(key, (R, D), dtype)
     s = _rand(jax.random.fold_in(key, 1), (D,), jnp.float32)
-    y = ops.rmsnorm(x, s, block_rows=br)
+    y = rmsnorm(x, s, block_rows=br, interpret=True)
     y_ref = ref.reference_rmsnorm(x, s)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(y_ref, np.float32),
@@ -127,3 +131,22 @@ def test_model_ssd_chunked_vs_oracle():
     np.testing.assert_allclose(
         np.asarray(state.transpose(0, 1, 3, 2).reshape(B * H, N, P)),
         np.asarray(st_ref), atol=3e-5, rtol=3e-5)
+
+
+def test_model_ssd_chunked_grads_finite_at_long_chunks():
+    """At mamba2's chunk of 256 the decay above the diagonal overflows
+    exp(); the mask must keep it out of the gradient."""
+    from repro.models.ssm import ssd_chunked
+    key = jax.random.PRNGKey(13)
+    B, S, H, P, G, N = 1, 256, 2, 8, 1, 8
+    xh = jax.random.normal(key, (B, S, H, P))
+    dt = jnp.full((B, S, H), 2.0)          # strong decay: cum spans >> 88
+    Bm = jax.random.normal(jax.random.fold_in(key, 1), (B, S, G, N))
+    Cm = jax.random.normal(jax.random.fold_in(key, 2), (B, S, G, N))
+
+    def loss(xh, dt, Bm, Cm):
+        y, state = ssd_chunked(xh, dt, jnp.zeros((H,)), Bm, Cm, 256)
+        return jnp.sum(y ** 2) + jnp.sum(state ** 2)
+    grads = jax.grad(loss, argnums=(0, 1, 2, 3))(xh, dt, Bm, Cm)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
