@@ -13,7 +13,7 @@ import time
 import uuid
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict
 
 from repro.core.ir import WorkflowIR
 
@@ -43,10 +43,6 @@ class StepRecord:
     # restarted engine can reconstruct the completion frontier from cache
     # hits (repro.core.faults.restore_frontier)
     cache_key: str = ""
-    # compute-layer profile (LocalEngine profile_steps=True): compile_s /
-    # execute_s split and device memory, folded into registry histograms
-    # and span annotations by the gateway
-    profile: Optional[Dict[str, float]] = None
 
     def duration(self) -> float:
         return max(0.0, self.end - self.start)

@@ -47,6 +47,9 @@ from repro.core.gateway.channels import (StepContext, StreamBroken,
                                          StreamCancelled, StreamReader,
                                          StreamRewound)
 from repro.core.ir import Job, WorkflowIR
+# a module reference, not a name: ``obs.spans`` imports the gateway's
+# events, so it may be half loaded while this module loads
+from repro.core.obs import spans as obs_spans
 
 
 def _hash_value(v: Any) -> str:
@@ -100,14 +103,8 @@ class LocalEngine(Engine):
                  telemetry_interval_s: float = 0.0,
                  anomaly=None,
                  slo=None,
-                 telemetry_path=None,
-                 profile_steps: bool = False):
+                 telemetry_path=None):
         self.max_workers = max_workers
-        # compute-layer profiling: jit compile-vs-execute split (AOT
-        # lower/compile when the step fn supports it) recorded on
-        # StepRecord.profile. Bypasses speculation — a profiled step is
-        # measured, not raced.
-        self.profile_steps = profile_steps
         self.cache = cache if cache is not None else CacheStore(
             capacity_bytes=1 << 30, policy=CoulerPolicy())
         self.budget = budget or Budget()
@@ -249,8 +246,16 @@ class LocalEngine(Engine):
     # ------------------------------------------------------------------
     def _exec_step(self, job: Job, run: WorkflowRun,
                    ctx: Optional[StepContext] = None) -> StepStatus:
-        if job.stream_output or job.stream_input:
-            return self._exec_stream_step(job, run, ctx)
+        """Run one step on the calling worker thread inside a
+        ``couler.step:<name>`` profiler span: the condition, the cache
+        check, the retry loop and the fn."""
+        with obs_spans.span(f"couler.step:{job.name}"):
+            if job.stream_output or job.stream_input:
+                return self._exec_stream_step(job, run, ctx)
+            return self._exec_plain_step(job, run, ctx)
+
+    def _exec_plain_step(self, job: Job, run: WorkflowRun,
+                         ctx: Optional[StepContext]) -> StepStatus:
         rec = run.steps[job.name]
         rec.start = time.time()
         rec.status = StepStatus.RUNNING
@@ -649,9 +654,6 @@ class LocalEngine(Engine):
             kwargs["ckpt"] = self._ckpt_session(job, run, mid_kill)
             return job.fn(*args, **kwargs)
 
-        if self.profile_steps:
-            return self._profiled_invoke(job, run, args)
-
         if not self.enable_speculation or _is_device_step(job, args):
             return job.fn(*args, **job.kwargs)
 
@@ -704,29 +706,6 @@ class LocalEngine(Engine):
             self._spec_pool_release(
                 spec_pool, busy=any(not f.done() for f in futures))
 
-    def _profiled_invoke(self, job: Job, run: WorkflowRun, args: List[Any]):
-        """Invoke with compute-layer profiling (``profile_steps=True``):
-        when the fn is jitted (``fn.lower(...).compile()``) the compile and
-        execute phases are timed separately; otherwise the plain call is
-        timed whole. A lower or compile error propagates like any other
-        step error. The profile lands on ``StepRecord.profile``; the
-        gateway folds it into histograms and span annotations."""
-        fn = job.fn
-        prof: Dict[str, float] = {}
-        if hasattr(fn, "lower"):
-            t0 = time.time()
-            fn = fn.lower(*args, **job.kwargs).compile()
-            prof["compile_s"] = time.time() - t0
-        t1 = time.time()
-        value = fn(*args, **job.kwargs)
-        _block_until_ready(value)
-        prof["execute_s"] = time.time() - t1
-        mem = _device_memory_bytes()
-        if mem is not None:
-            prof["device_bytes_in_use"] = float(mem)
-        run.steps[job.name].profile = prof
-        return value
-
 
 def _is_device_step(job: Job, args: List[Any]) -> bool:
     """A step that declares an accelerator (``Resources.gpu > 0``) or is
@@ -741,28 +720,3 @@ def _is_device_step(job: Job, args: List[Any]) -> bool:
         return False
     return any(isinstance(leaf, jax.Array)
                for leaf in jax.tree_util.tree_leaves((args, job.kwargs)))
-
-
-def _block_until_ready(v: Any) -> None:
-    """Wait until every jax array in the output pytree is computed, so
-    execute_s measures device time; device errors propagate. A no-op when
-    jax was never imported (no value can hold a device array)."""
-    jax = sys.modules.get("jax")
-    if jax is not None:
-        jax.block_until_ready(v)
-
-
-def _device_memory_bytes() -> Optional[int]:
-    """bytes_in_use of the first jax device, when the backend exposes
-    memory_stats (CPU backends typically return None)."""
-    try:
-        import jax
-        devs = jax.local_devices()
-        if not devs:
-            return None
-        stats = devs[0].memory_stats()
-        if stats:
-            return stats.get("bytes_in_use")
-    except Exception:   # noqa: BLE001 — profiling never fails a step
-        return None
-    return None

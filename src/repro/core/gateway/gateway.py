@@ -64,6 +64,9 @@ from repro.core.gateway.events import EventType
 from repro.core.gateway.run import AsyncWorkflowRun
 from repro.core.ir import WorkflowIR
 from repro.core.obs.metrics import MetricsRegistry, StatsView
+# a module reference, not a name: ``obs.spans`` imports the gateway's
+# events, so it may be half loaded while this module loads
+from repro.core.obs import spans as obs_spans
 
 _EVENT_FOR_STATUS = {
     StepStatus.SUCCEEDED: EventType.STEP_SUCCEEDED,
@@ -159,6 +162,8 @@ class WorkflowGateway:
         self._pump_task: Optional[asyncio.Task] = None
         self._promote_task: Optional[asyncio.Task] = None
         self._wf_tasks: Set[asyncio.Task] = set()
+        # the open ``couler.idle`` profiler span, if any (loop thread only)
+        self._idle_span = None
         self._start_lock = threading.Lock()
         self._started = threading.Event()
         self._closed = False
@@ -207,9 +212,11 @@ class WorkflowGateway:
         if self.telemetry_interval_s and self.tsdb is not None:
             self._telemetry_task = loop.create_task(self._telemetry_loop())
         self._started.set()
+        self._begin_idle()
         try:
             loop.run_forever()
         finally:
+            self._end_idle()
             loop.close()
 
     def _cache_promotable(self) -> bool:
@@ -594,11 +601,14 @@ class WorkflowGateway:
         async def exec_one(name: str) -> None:
             status: Optional[StepStatus] = None
             try:
-                async with self._step_sem:
+                with obs_spans.span("couler.queue_wait"):
+                    await self._step_sem.acquire()
+                try:
                     if handle.cancel_requested:
                         return              # never launched: stays Pending
                     handle._publish(EventType.STEP_STARTED, step=name)
-                    self._note_inflight(+1)
+                    if self._note_inflight(+1) == 1:
+                        self._end_idle()
                     try:
                         status = await loop.run_in_executor(
                             self._pool, eng._exec_step, wfp.jobs[name], run,
@@ -615,7 +625,8 @@ class WorkflowGateway:
                         rec.status = StepStatus.FAILED
                         status = StepStatus.FAILED
                     finally:
-                        self._note_inflight(-1)
+                        if self._note_inflight(-1) == 0:
+                            self._begin_idle()
                     if status is not None:
                         handle._publish(
                             _EVENT_FOR_STATUS.get(status,
@@ -623,12 +634,11 @@ class WorkflowGateway:
                             step=name, status=status.value,
                             error=run.steps[name].error)
                         self._record_frontier(run)
-                        prof = getattr(run.steps[name], "profile", None)
-                        if prof:
-                            self._fold_profile(run, name, prof)
                         if self.anomaly is not None \
                                 and status is StepStatus.SUCCEEDED:
                             self._note_step_telemetry(handle, run, name)
+                finally:
+                    self._step_sem.release()
             finally:
                 finish_one(name, status)
 
@@ -665,11 +675,27 @@ class WorkflowGateway:
                     stream_stalls=st["stalls"],
                     stream_max_lead=st["max_lead"])
 
-    def _note_inflight(self, delta: int) -> None:
-        # thread-safe now (registry gauges): speculation reserves slots
-        # from worker threads, the loop thread drives exec_one — the old
-        # dict high-water update could lose peaks across those contexts
-        self._m_peak.set_max(self._m_inflight.add(delta))
+    def _note_inflight(self, delta: int) -> int:
+        """Add ``delta`` to the in-flight step count; returns the new
+        count. Thread-safe (registry gauges): speculation reserves slots
+        from worker threads, the loop thread drives exec_one."""
+        n = self._m_inflight.add(delta)
+        self._m_peak.set_max(n)
+        return int(n)
+
+    # -- the couler.idle profiler span (loop thread only) ----------------
+    def _begin_idle(self) -> None:
+        """No step is in flight: open ``couler.idle`` until the next step
+        starts, so a profiler trace tells the gaps between workflows from
+        host overhead inside them."""
+        if self._idle_span is None:
+            self._idle_span = obs_spans.span("couler.idle")
+            self._idle_span.__enter__()
+
+    def _end_idle(self) -> None:
+        if self._idle_span is not None:
+            self._idle_span.__exit__(None, None, None)
+            self._idle_span = None
 
     @property
     def _inflight_steps(self) -> int:
@@ -820,21 +846,6 @@ class WorkflowGateway:
                 return
             except Exception:  # noqa: BLE001 — telemetry is advisory
                 pass
-
-    def _fold_profile(self, run: WorkflowRun, step: str,
-                      prof: Dict[str, float]) -> None:
-        """Record a step's compute-layer profile (jit compile vs execute
-        split, device memory) as registry histograms/gauges and annotate
-        its span so ``run.report()`` shows the breakdown."""
-        m = self.registry
-        if "compile_s" in prof:
-            m.histogram("step_compile_s").observe(prof["compile_s"])
-        if "execute_s" in prof:
-            m.histogram("step_execute_s").observe(prof["execute_s"])
-        if "device_bytes_in_use" in prof:
-            m.gauge("device_bytes_in_use").set(prof["device_bytes_in_use"])
-        if self.collector is not None:
-            self.collector.annotate_step(run.run_id, step, **prof)
 
     def _note_step_telemetry(self, handle: AsyncWorkflowRun,
                              run: WorkflowRun, step: str) -> None:

@@ -29,10 +29,17 @@ Exports: ``export_jsonl`` (one span-tree object per line, loadable with
 ``load_jsonl`` for offline reports) and ``export_chrome`` (Chrome
 trace-event JSON, loadable in Perfetto / ``chrome://tracing``;
 ``validate_chrome_trace`` is the schema check the test suite pins).
+
+``span(name)`` is the other kind of span: a host span on the profiler's
+clock (``jax.profiler.TraceAnnotation``), which a ``jax.profiler`` trace
+records beside the device's planes. The gateway and the engine mark a
+step's lifecycle with it (``docs/observability.md``, "Profiler spans").
 """
 from __future__ import annotations
 
+import contextlib
 import json
+import sys
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -42,7 +49,20 @@ from repro.core.gateway.events import EventType, WorkflowEvent
 from repro.core.obs.metrics import MetricsRegistry
 
 __all__ = ["Segment", "StepSpan", "SpanTree", "ObsCollector",
-           "chrome_trace", "validate_chrome_trace", "load_jsonl"]
+           "chrome_trace", "validate_chrome_trace", "load_jsonl", "span"]
+
+
+def span(name: str):
+    """A host span named ``name`` on the profiler's clock: a
+    ``jax.profiler.TraceAnnotation`` when jax is already imported, else a
+    no-op, so that a host-only process never imports jax for it. The name
+    carries everything a reader needs: a trace reader keeps event names
+    and drops keyword arguments. It is ended on the thread that began it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
+
 
 #: step terminal statuses that satisfy successors
 SATISFIED = ("Succeeded", "Cached", "Skipped")

@@ -79,16 +79,29 @@ def train(cfg, tcfg, *, arch: str, strategy: str = "baseline",
         losses = []
         batches = synthetic_batches(batch, seq, cfg.vocab_size, seed=seed,
                                     n=steps)
-        for b in itertools.islice(batches, start or 0, None):
-            b = {k: jnp.asarray(v) for k, v in b.items()}
-            b = jax.device_put(b, to_named(batch_specs(b, mesh, rules), mesh))
-            state, m = step_fn(state, b)
+        # the step count on the host: the device's is read only where a
+        # step is logged or saved, so that no other step waits on it
+        s = start or 0
+        for b in itertools.islice(batches, s, None):
+            s += 1
+            with jax.profiler.StepTraceAnnotation("train", step_num=s):
+                b = {k: jnp.asarray(v) for k, v in b.items()}
+                b = jax.device_put(b, to_named(batch_specs(b, mesh, rules),
+                                               mesh))
+                state, m = step_fn(state, b)
             losses.append(m["loss"])
-            s = int(state["step"])
-            if s % log_every == 0:
+            log = s % log_every == 0
+            save = mgr is not None and s % ckpt_every == 0
+            if not (log or save):
+                continue
+            on_device = int(state["step"])
+            if on_device != s:
+                raise RuntimeError(f"the state is at step {on_device}, "
+                                   f"the loop at {s}")
+            if log:
                 print(f"step {s:5d} loss {float(m['loss']):.4f} "
                       f"gnorm {float(m['grad_norm']):.3f}")
-            if mgr and s % ckpt_every == 0:
+            if save:
                 mgr.async_save(s, state)
         if mgr:
             mgr.wait()

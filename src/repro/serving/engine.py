@@ -16,6 +16,8 @@ import jax.numpy as jnp
 
 from repro.models import transformer as T
 
+span = jax.profiler.TraceAnnotation
+
 
 @dataclass
 class GenerationResult:
@@ -35,24 +37,35 @@ class ServingEngine:
         self.params = params
         self.max_len = max_len
         self.cache_dtype = cache_dtype
-        self._step = jax.jit(
-            lambda p, t, c, i: T.apply_lm_decode(p, cfg, t, c, i))
+
+        def decode_step(p, t, c, i):
+            return T.apply_lm_decode(p, cfg, t, c, i)
+
+        # named, so that its module reads ``jit_decode_step`` in a trace
+        self._step = jax.jit(decode_step)
 
     def generate(self, prompts: jax.Array, gen_len: int,
                  temperature: float = 0.0, seed: int = 0) -> GenerationResult:
-        """prompts: (B, P) int32 token batch -> greedy/temp decode."""
+        """prompts: (B, P) int32 token batch -> greedy/temp decode.
+
+        Its phases are host spans on the profiler's clock:
+        ``serve.cache_init``, ``serve.prefill``, ``serve.decode`` (one
+        ``serve.token`` per decode call inside it) and ``serve.fetch``."""
         B, P = prompts.shape
         assert P + gen_len <= self.max_len
-        caches = T.init_caches(self.cfg, B, self.max_len, self.cache_dtype)
+        with span("serve.cache_init"):
+            caches = T.init_caches(self.cfg, B, self.max_len,
+                                   self.cache_dtype)
         key = jax.random.PRNGKey(seed)
 
-        t0 = time.time()
-        logits = None
-        for i in range(P):                      # prefill via the decode path
-            logits, caches = self._step(self.params, prompts[:, i:i + 1],
-                                        caches, jnp.int32(i))
-        jax.block_until_ready((logits, caches))
-        prefill_s = time.time() - t0
+        with span("serve.prefill"):
+            t0 = time.time()
+            logits = None
+            for i in range(P):                  # prefill via the decode path
+                logits, caches = self._step(self.params, prompts[:, i:i + 1],
+                                            caches, jnp.int32(i))
+            jax.block_until_ready((logits, caches))
+            prefill_s = time.time() - t0
         prompt_logits = logits[:, -1]
 
         def sample(lg, k):
@@ -60,17 +73,22 @@ class ServingEngine:
                 return jnp.argmax(lg[:, -1], -1)[:, None]
             return jax.random.categorical(k, lg[:, -1] / temperature)[:, None]
 
-        t0 = time.time()
-        tok = sample(logits, key)
-        out = [tok]
-        for i in range(P, P + gen_len - 1):
-            logits, caches = self._step(self.params, tok, caches, jnp.int32(i))
-            key = jax.random.fold_in(key, i)
+        with span("serve.decode"):
+            t0 = time.time()
             tok = sample(logits, key)
-            out.append(tok)
-        gen = jax.block_until_ready(jnp.concatenate(out, axis=1))
-        decode_s = time.time() - t0
+            out = [tok]
+            for i in range(P, P + gen_len - 1):
+                with span("serve.token"):
+                    logits, caches = self._step(self.params, tok, caches,
+                                                jnp.int32(i))
+                    key = jax.random.fold_in(key, i)
+                    tok = sample(logits, key)
+                out.append(tok)
+            gen = jax.block_until_ready(jnp.concatenate(out, axis=1))
+            decode_s = time.time() - t0
+        with span("serve.fetch"):
+            tokens = gen.tolist()
         return GenerationResult(
-            tokens=gen.tolist(), prefill_s=prefill_s, decode_s=decode_s,
+            tokens=tokens, prefill_s=prefill_s, decode_s=decode_s,
             tokens_per_s=B * gen.shape[1] / max(decode_s, 1e-9),
             prompt_logits=prompt_logits)

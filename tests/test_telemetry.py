@@ -13,8 +13,6 @@ the ``TraceChecker`` (invariant 9).
 import random
 import time
 
-import jax
-import jax.numpy as jnp
 import pytest
 
 from repro.core import couler
@@ -459,33 +457,3 @@ class TestTelemetryAPI:
         back = TimeSeriesDB.load_jsonl(str(path))
         assert back.samples_taken >= 2
         assert back.latest("gateway_workflows_submitted_total") >= 1.0
-
-
-class TestStepProfiling:
-    def test_plain_fn_profile_recorded(self):
-        eng = _engine(max_workers=2, profile_steps=True)
-        try:
-            run = eng.submit(_chain_wf("prof", n=2))
-            assert run.succeeded()
-            prof = run.steps["s0"].profile
-            assert prof is not None and "execute_s" in prof
-            snap = eng.gateway.registry.snapshot()
-            assert snap["step_execute_s"]["count"] >= 2
-        finally:
-            eng.close()
-
-    def test_jit_fn_splits_compile_and_execute(self):
-        fn = jax.jit(lambda: jnp.asarray(2.0) * 3.0)
-        eng = _engine(max_workers=2, profile_steps=True)
-        try:
-            wf = WorkflowIR("profjit")
-            wf.add_job(Job(name="s0", fn=fn, cacheable=False))
-            run = eng.submit(wf)
-            assert run.succeeded()
-            prof = run.steps["s0"].profile
-            assert prof is not None
-            assert prof["compile_s"] > 0 and prof["execute_s"] > 0
-            snap = eng.gateway.registry.snapshot()
-            assert snap["step_compile_s"]["count"] >= 1
-        finally:
-            eng.close()
