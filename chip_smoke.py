@@ -11,8 +11,9 @@ drawn from a seed) runs as one fine-tune-and-serve workflow through
     train  TRAIN_STEPS steps at BATCH x SEQ tokens of ``synthetic_batches``
     serve  N_REQUESTS requests through ``ServingEngine`` with the trained
            parameters (PROMPT_LEN prompt tokens, GEN_LEN generated)
-    check  the decode path's logits at the last prompt position against
-           ``T.apply_lm``'s full forward on the same prompts
+    check  the serving engine's logits at the last prompt position (its
+           one-call prefill) against ``T.apply_lm``'s full forward on the
+           same prompts
 
 It fails unless the run succeeded with no step retried or speculated, the
 loss is finite and lower at the last step than at the first, and the two
@@ -61,15 +62,16 @@ SEED = 0
 ARCH = "mamba2-370m"
 BATCH, SEQ, TRAIN_STEPS = 8, 2048, 10
 N_REQUESTS, PROMPT_LEN, GEN_LEN = 8, 128, 32
-# Largest decode-vs-forward logit difference allowed, as a share of the
+# Largest serve-vs-forward logit difference allowed, as a share of the
 # largest logit. Both paths run in bf16, which keeps 8 significant bits
-# (2^-8 ~ 0.4% per rounding), and they round different intermediates: the
-# full forward runs the chunked SSD scan over the whole prompt, the decode
-# path carries the recurrent state token by token. The gap therefore grows
-# with depth, about as sqrt(layers): on a CPU at cut widths it was 1.7% at
-# 2 layers and 8% at 48 (d_model 64), 2.8% at 8 layers with d_model 1024,
-# and 4e-6 at 48 layers in float32, so it is rounding alone. A wrong state
-# or cache hand-off differs by the order of the logits themselves.
+# (2^-8 ~ 0.4% per rounding). The limit was set when the serve path carried
+# the recurrent state token by token, rounding other intermediates than the
+# full forward's chunked SSD scan; its prefill now runs that scan over the
+# prompt padded to the engine's max_len. That gap grew with depth, about as
+# sqrt(layers): on a CPU at cut widths it was 1.7% at 2 layers and 8% at 48
+# (d_model 64), 2.8% at 8 layers with d_model 1024, and 4e-6 at 48 layers
+# in float32, so it is rounding alone. A wrong state or cache hand-off
+# differs by the order of the logits themselves.
 LOGIT_RTOL = 0.15
 
 FOUR_ARCH = "stablelm-1.6b"
@@ -156,7 +158,7 @@ def serve_step(trained, cfg, seed: int, n: int = N_REQUESTS,
         n, prompt_len, cfg.vocab_size, seed=seed, n=1))["tokens"])
     eng = ServingEngine(cfg, trained["params"], max_len=prompt_len + gen_len)
     t0 = time.perf_counter()
-    cold = eng.generate(prompts, gen_len)       # compiles the decode step
+    cold = eng.generate(prompts, gen_len)       # compiles prefill and decode
     t1 = time.perf_counter()
     warm = eng.generate(prompts, gen_len)
     t2 = time.perf_counter()

@@ -1,21 +1,20 @@
-"""Serve a small LM with batched requests: prefill via sequential cache
-fill + batched decode steps (the serve_step that the decode_32k /
-long_500k dry-run cells lower at production scale).
+"""Serve a small LM with batched requests through ``ServingEngine``: the
+prompt fills the caches (in one call for the dense and ssm families), then
+batched greedy decode steps.
 
     PYTHONPATH=src python examples/serve_lm.py [--arch mamba2-370m]
 """
 import argparse
 import sys
-import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 import jax
-import jax.numpy as jnp
 
 from repro.configs import get_arch, reduced
 from repro.models import transformer as T
+from repro.serving.engine import ServingEngine
 
 
 def main():
@@ -30,39 +29,18 @@ def main():
     cfg = reduced(spec.model).replace(param_dtype="float32",
                                       compute_dtype="float32")
     params = T.init_lm(jax.random.PRNGKey(0), cfg)
-    max_len = args.prompt_len + args.gen_len
-    caches = T.init_caches(cfg, args.batch, max_len, jnp.float32)
-
-    step = jax.jit(lambda p, t, c, i: T.apply_lm_decode(p, cfg, t, c, i))
+    eng = ServingEngine(cfg, params, max_len=args.prompt_len + args.gen_len)
     prompts = jax.random.randint(jax.random.PRNGKey(1),
                                  (args.batch, args.prompt_len), 0,
                                  cfg.vocab_size)
+    res = eng.generate(prompts, args.gen_len)
 
-    # prefill: feed prompt tokens through the decode path to fill caches
-    t0 = time.time()
-    for i in range(args.prompt_len):
-        logits, caches = step(params, prompts[:, i:i + 1], caches,
-                              jnp.int32(i))
-    prefill_s = time.time() - t0
-
-    # batched greedy decode
-    tok = jnp.argmax(logits[:, -1], -1)[:, None]
-    out = [tok]
-    t0 = time.time()
-    for i in range(args.prompt_len, max_len - 1):
-        logits, caches = step(params, tok, caches, jnp.int32(i))
-        tok = jnp.argmax(logits[:, -1], -1)[:, None]
-        out.append(tok)
-    decode_s = time.time() - t0
-    gen = jnp.concatenate(out, axis=1)
-
-    tps = args.batch * gen.shape[1] / decode_s
     print(f"arch={args.arch} family={cfg.family}")
     print(f"prefill: {args.prompt_len} toks x {args.batch} reqs "
-          f"in {prefill_s:.2f}s")
-    print(f"decode:  {gen.shape[1]} toks x {args.batch} reqs "
-          f"in {decode_s:.2f}s ({tps:.1f} tok/s)")
-    print("sample token ids:", gen[0, :12].tolist())
+          f"in {res.prefill_s:.2f}s ({res.prefill_calls} device calls)")
+    print(f"decode:  {args.gen_len} toks x {args.batch} reqs "
+          f"in {res.decode_s:.2f}s ({res.tokens_per_s:.1f} tok/s)")
+    print("sample token ids:", res.tokens[0][:12])
 
 
 if __name__ == "__main__":

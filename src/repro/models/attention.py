@@ -1,10 +1,12 @@
-"""Attention: GQA/MHA and MLA (DeepSeek latent), full + decode paths.
+"""Attention: GQA/MHA and MLA (DeepSeek latent), full + prefill + decode paths.
 
 Full-sequence attention is *blockwise* (lax.scan over KV blocks with online
 softmax — flash-attention semantics at the XLA level) so that 32k-token
 prefill never materializes the (S x S) score matrix. The per-block body is
 wrapped in ``jax.checkpoint`` so the autodiff backward recomputes block
-scores instead of saving O(S^2) residuals.
+scores instead of saving O(S^2) residuals. The GQA prefill path is the
+full path that also writes the prompt's rotated keys and values into the
+decode cache.
 
 Decode attends a single new token against a KV cache laid out
 (batch, kv_heads, seq, head_dim) so the sharding resolver prefers
@@ -103,8 +105,8 @@ def blockwise_attention(q, k, v, qpos, kpos, prefix_len=None,
     return out.astype(q.dtype)
 
 
-def apply_attention_full(p, cfg, x, positions, prefix_len=None):
-    """x: (B,S,D_in) -> (B,S,D). Causal (or prefix-LM) full attention."""
+def _qkv(p, cfg, x, positions):
+    """x: (B,S,D_in) -> rotated q (B,S,H,hd), rotated k and v (B,S,KH,hd)."""
     B, S, _ = x.shape
     hd, H, KH = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
     dt = x.dtype
@@ -113,6 +115,14 @@ def apply_attention_full(p, cfg, x, positions, prefix_len=None):
     v = (x @ p["wv"].astype(dt)).reshape(B, S, KH, hd)
     q = L.apply_rope(q, positions, cfg.rope_theta)
     k = L.apply_rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def _attend(p, q, k, v, positions, prefix_len):
+    """Blockwise attention of rotated q over k, v, then the out projection."""
+    B, S, H, hd = q.shape
+    KH = k.shape[2]
+    dt = q.dtype
     if KH != H:
         rep = H // KH
         k = jnp.repeat(k, rep, axis=2)
@@ -124,6 +134,29 @@ def apply_attention_full(p, cfg, x, positions, prefix_len=None):
     out = blockwise_attention(q, k, v, qpos, qpos, prefix_len)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
     return out @ p["wo"].astype(dt)
+
+
+def apply_attention_full(p, cfg, x, positions, prefix_len=None):
+    """x: (B,S,D_in) -> (B,S,D). Causal (or prefix-LM) full attention."""
+    q, k, v = _qkv(p, cfg, x, positions)
+    return _attend(p, q, k, v, positions, prefix_len)
+
+
+def apply_attention_prefill(p, cfg, x, positions, cache):
+    """Causal full attention that also writes the rotated k and v of every
+    position of x into the cache (B,KH,S_cache,hd) from position 0: the
+    cache ``apply_attention_decode`` leaves after S calls, up to rounding.
+
+    Returns (out (B,S,D), new_cache)."""
+    q, k, v = _qkv(p, cfg, x, positions)
+    out = _attend(p, q, k, v, positions, None)
+    k_c = jax.lax.dynamic_update_slice(
+        cache["k"], k.transpose(0, 2, 1, 3).astype(cache["k"].dtype), (0, 0, 0, 0))
+    v_c = jax.lax.dynamic_update_slice(
+        cache["v"], v.transpose(0, 2, 1, 3).astype(cache["v"].dtype), (0, 0, 0, 0))
+    k_c = shard(k_c, "batch", "kv_heads", "kv_seq", None)
+    v_c = shard(v_c, "batch", "kv_heads", "kv_seq", None)
+    return out, {"k": k_c, "v": v_c}
 
 
 def init_kv_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):

@@ -119,27 +119,62 @@ def ssd_chunked(xh, dt, a_log, Bm, Cm, chunk: int):
     return y.astype(xh.dtype), final.transpose(0, 1, 3, 2)  # state (B,H,P,N)
 
 
-def apply_ssm_full(p, cfg, x):
-    """x: (B,S,D) -> (B,S,D). Full-sequence chunked SSD."""
+def _ssm_seq(p, cfg, x, length=None):
+    """Full-sequence chunked SSD over x: (B,S,D).
+
+    Returns (out (B,S,D), final state (B,H,P,N) f32, the raw pre-conv
+    projections (x, B, C), each (B,S,C)). With ``length`` (a traced int32),
+    dt is 0 at positions >= length: their decay is 1 and their input 0, so
+    the final state is the state after the first ``length`` positions."""
     B, S, D = x.shape
     d_in, H, G, N = dims(cfg)
     dt_ = x.dtype
     z = x @ p["in_z"].astype(dt_)
-    xs = _causal_conv(x @ p["in_x"].astype(dt_), p["conv_x"].astype(dt_))
-    Bm = _causal_conv(x @ p["in_B"].astype(dt_), p["conv_B"].astype(dt_))
-    Cm = _causal_conv(x @ p["in_C"].astype(dt_), p["conv_C"].astype(dt_))
+    xs_raw = x @ p["in_x"].astype(dt_)
+    xs = _causal_conv(xs_raw, p["conv_x"].astype(dt_))
+    Bm_raw = x @ p["in_B"].astype(dt_)
+    Bm = _causal_conv(Bm_raw, p["conv_B"].astype(dt_))
+    Cm_raw = x @ p["in_C"].astype(dt_)
+    Cm = _causal_conv(Cm_raw, p["conv_C"].astype(dt_))
     xs, Bm, Cm = jax.nn.silu(xs), jax.nn.silu(Bm), jax.nn.silu(Cm)
     dt = jax.nn.softplus((x @ p["in_dt"].astype(dt_)).astype(jnp.float32)
                          + p["dt_bias"][None, None, :])
+    if length is not None:
+        dt = jnp.where(jnp.arange(S)[None, :, None] < length, dt, 0.0)
 
     xh = shard(xs.reshape(B, S, H, cfg.ssm_head_dim), "batch", None, "ssm_heads", None)
     Bm = Bm.reshape(B, S, G, N)
     Cm = Cm.reshape(B, S, G, N)
-    y, _ = ssd_chunked(xh, dt, p["A_log"], Bm, Cm, cfg.ssm_chunk)
+    y, state = ssd_chunked(xh, dt, p["A_log"], Bm, Cm, cfg.ssm_chunk)
     y = y + xh * p["D_skip"].astype(dt_)[None, None, :, None]
     y = y.reshape(B, S, d_in)
     y = L.apply_rmsnorm(p["gate_norm"], y * jax.nn.silu(z), cfg.norm_eps)
-    return y @ p["out"].astype(dt_)
+    return y @ p["out"].astype(dt_), state, (xs_raw, Bm_raw, Cm_raw)
+
+
+def apply_ssm_full(p, cfg, x):
+    """x: (B,S,D) -> (B,S,D). Full-sequence chunked SSD."""
+    return _ssm_seq(p, cfg, x)[0]
+
+
+def apply_ssm_prefill(p, cfg, x, length, cache):
+    """x: (B,S,D), right-padded past ``length`` (traced int32, 1..S).
+
+    Returns (out (B,S,D), the cache ``apply_ssm_decode`` leaves after the
+    first ``length`` positions, up to rounding): the SSM state, and each
+    conv window holding the raw projections at positions length-K+1 ..
+    length-1, zeros before position 0."""
+    out, state, raws = _ssm_seq(p, cfg, x, length)
+    K = cfg.ssm_conv
+
+    def window(u, c):
+        u = jnp.pad(u, ((0, 0), (K - 1, 0), (0, 0)))
+        return jax.lax.dynamic_slice_in_dim(u, length, K - 1,
+                                            axis=1).astype(c.dtype)
+    new = {"state": state.astype(cache["state"].dtype)}
+    for name, u in zip(("conv_x", "conv_B", "conv_C"), raws):
+        new[name] = window(u, cache[name])
+    return out, new
 
 
 def init_ssm_cache(cfg, batch: int, dtype=jnp.float32):

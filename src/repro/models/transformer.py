@@ -5,8 +5,10 @@ axis) so the HLO stays small and compile time flat in depth — required for
 the 61-layer / 671B dry-run. Remat policy ("none" | "dots" | "full") wraps
 the scanned layer body.
 
-``apply_lm``         : full-sequence forward -> (logits, aux)  [train/prefill]
+``apply_lm``         : full-sequence forward -> (logits, aux)  [train]
 ``apply_lm_decode``  : one-token forward with caches -> (logits, new_caches)
+``apply_lm_prefill`` : padded-prompt forward that writes the decode caches
+                       -> (last-position logits, caches)  [dense, ssm]
 ``init_lm``/``init_caches`` build the matching parameter / cache pytrees.
 """
 from __future__ import annotations
@@ -534,3 +536,65 @@ def apply_lm_decode(params, cfg, token, caches, index):
 
     h = L.apply_rmsnorm(params["final_norm"], h, cfg.norm_eps)
     return _head(params, cfg, h), caches
+
+
+# ---------------------------------------------------------------------------
+# prefill: one call that writes the decode caches
+# ---------------------------------------------------------------------------
+
+# the families ``apply_lm_prefill`` covers; the others prefill through
+# ``apply_lm_decode``, one call a prompt token
+PREFILL_FAMILIES = ("dense", "ssm")
+
+
+def prefill_len(cfg, max_len: int) -> int:
+    """The padded prompt length of ``apply_lm_prefill`` for caches of
+    ``max_len``: max_len itself, rounded up to a multiple of ``ssm_chunk``
+    for SSM layers when it exceeds one chunk (``ssd_chunked``'s need)."""
+    if cfg.family == "ssm" and max_len > cfg.ssm_chunk:
+        return -(-max_len // cfg.ssm_chunk) * cfg.ssm_chunk
+    return max_len
+
+
+def apply_lm_prefill(params, cfg, tokens, length, caches):
+    """tokens: (B,S) int32, the prompt right-padded to S = ``prefill_len``;
+    length: scalar int32 (traced) true prompt length, 1..S; caches: as
+    ``init_caches`` builds them.
+
+    Returns (logits (B,1,V) at position length-1, the caches that
+    ``length`` calls of ``apply_lm_decode`` leave, up to rounding). Causal
+    masking keeps every position below ``length`` exact; cache entries at
+    or past it hold padding, which decode overwrites before it reads them.
+    """
+    cdt = _cdt(cfg)
+    B, Sp = tokens.shape
+    h = L.apply_embed({"table": params["embed"]["table"]}, tokens).astype(cdt)
+    h = shard(h, "batch", None, None)
+    positions = jnp.broadcast_to(jnp.arange(Sp, dtype=jnp.int32)[None], (B, Sp))
+
+    fam = cfg.family
+    if fam == "dense":
+        def step(hh, xs):
+            lp, cache = xs
+            a, nc = A.apply_attention_prefill(
+                lp["attn"], cfg, L.apply_rmsnorm(lp["ln1"], hh, cfg.norm_eps),
+                positions, cache)
+            hh = hh + a
+            hh = hh + L.apply_mlp(lp["mlp"],
+                                  L.apply_rmsnorm(lp["ln2"], hh, cfg.norm_eps),
+                                  cfg.act)
+            return shard(hh, "batch", None, None), nc
+    elif fam == "ssm":
+        def step(hh, xs):
+            lp, cache = xs
+            y, nc = S.apply_ssm_prefill(
+                lp["ssm"], cfg, L.apply_rmsnorm(lp["ln"], hh, cfg.norm_eps),
+                length, cache)
+            return shard(hh + y, "batch", None, None), nc
+    else:
+        raise ValueError(f"no one-call prefill for family {fam!r}")
+    h, new = jax.lax.scan(step, h, (params["layers"], caches["layers"]))
+
+    h = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
+    h = L.apply_rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    return _head(params, cfg, h), {"layers": new}

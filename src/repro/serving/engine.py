@@ -3,7 +3,9 @@
 Wraps the per-family decode paths (KV cache for attention families,
 O(1) recurrent state for SSM/hybrid) behind one request-batch API. The
 ``serve_step`` this engine jits is the same function the ``decode_32k`` /
-``long_500k`` dry-run cells lower at production scale.
+``long_500k`` dry-run cells lower at production scale. A prompt of the
+families in ``T.PREFILL_FAMILIES`` fills the caches in one call; the other
+families feed it through the decode step, one call a prompt token.
 """
 from __future__ import annotations
 
@@ -13,6 +15,7 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models import transformer as T
 
@@ -28,6 +31,9 @@ class GenerationResult:
     # (B, V) float32 logits at the last prompt position, from which the
     # first generated token is sampled
     prompt_logits: Optional[jax.Array] = None
+    # device calls the prefill took: 1 through ``jit_prefill``, else one
+    # decode call a prompt token
+    prefill_calls: int = 0
 
 
 class ServingEngine:
@@ -43,6 +49,15 @@ class ServingEngine:
 
         # named, so that its module reads ``jit_decode_step`` in a trace
         self._step = jax.jit(decode_step)
+        self._prefill = None
+        if cfg.family in T.PREFILL_FAMILIES:
+            def prefill(p, t, n, c):
+                return T.apply_lm_prefill(p, cfg, t, n, c)
+
+            # module ``jit_prefill``; one program per (batch, max_len): the
+            # prompt is padded to ``_prefill_len`` and its length is traced
+            self._prefill = jax.jit(prefill, donate_argnums=(3,))
+            self._prefill_len = T.prefill_len(cfg, max_len)
 
     def generate(self, prompts: jax.Array, gen_len: int,
                  temperature: float = 0.0, seed: int = 0) -> GenerationResult:
@@ -52,7 +67,9 @@ class ServingEngine:
         ``serve.cache_init``, ``serve.prefill``, ``serve.decode`` (one
         ``serve.token`` per decode call inside it) and ``serve.fetch``."""
         B, P = prompts.shape
-        assert P + gen_len <= self.max_len
+        if not 1 <= P <= self.max_len - gen_len:
+            raise ValueError(f"a prompt of {P} tokens and {gen_len} generated "
+                             f"do not fit max_len {self.max_len}")
         with span("serve.cache_init"):
             caches = T.init_caches(self.cfg, B, self.max_len,
                                    self.cache_dtype)
@@ -60,10 +77,18 @@ class ServingEngine:
 
         with span("serve.prefill"):
             t0 = time.time()
-            logits = None
-            for i in range(P):                  # prefill via the decode path
-                logits, caches = self._step(self.params, prompts[:, i:i + 1],
-                                            caches, jnp.int32(i))
+            if self._prefill is not None:
+                # padded on the host: a device pad would compile once per P
+                padded = np.zeros((B, self._prefill_len), np.int32)
+                padded[:, :P] = np.asarray(prompts)
+                logits, caches = self._prefill(self.params, padded,
+                                               np.int32(P), caches)
+                prefill_calls = 1
+            else:
+                for i in range(P):          # prefill via the decode path
+                    logits, caches = self._step(
+                        self.params, prompts[:, i:i + 1], caches, jnp.int32(i))
+                prefill_calls = P
             jax.block_until_ready((logits, caches))
             prefill_s = time.time() - t0
         prompt_logits = logits[:, -1]
@@ -91,4 +116,4 @@ class ServingEngine:
         return GenerationResult(
             tokens=tokens, prefill_s=prefill_s, decode_s=decode_s,
             tokens_per_s=B * gen.shape[1] / max(decode_s, 1e-9),
-            prompt_logits=prompt_logits)
+            prompt_logits=prompt_logits, prefill_calls=prefill_calls)

@@ -74,8 +74,10 @@ def test_serve_spans_bound_the_phases_of_generate(tmp_path):
 
 
 def test_generate_matches_the_plain_decode_loop():
-    """The spans change neither the calls nor the numbers: greedy tokens
-    equal those of a plain loop over the same decode function."""
+    """Greedy tokens equal those of a plain loop over the same decode
+    function, spans and all. The prompt
+    is prefilled in one call, which sums in another order than the loop,
+    so its logits agree within float32 rounding, not bit for bit."""
     cfg, eng = small_engine()
     prompts = jax.random.randint(jax.random.PRNGKey(3), (2, 5), 0, 100)
     got = eng.generate(prompts, gen_len=6)
@@ -84,7 +86,9 @@ def test_generate_matches_the_plain_decode_loop():
     for i in range(5):
         logits, caches = step(eng.params, prompts[:, i:i + 1], caches,
                               jnp.int32(i))
-    assert jnp.array_equal(got.prompt_logits, logits[:, -1])
+    want_logits = logits[:, -1]
+    assert float(jnp.max(jnp.abs(got.prompt_logits - want_logits))) <= (
+        1e-4 * float(jnp.max(jnp.abs(want_logits))))
     want = [jnp.argmax(logits[:, -1], -1)[:, None]]
     for i in range(5, 10):
         logits, caches = step(eng.params, want[-1], caches, jnp.int32(i))

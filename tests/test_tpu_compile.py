@@ -1,6 +1,6 @@
 """Compiles for a described TPU v5e, with no chip attached: the Pallas
-kernels at real widths, and the one-chip check's train step against the
-chip's memory. A compile that passes is not a chip run; it catches what
+kernels at real widths, and the one-chip check's train step and the
+serving engine's prefill against the chip's memory. A compile that passes is not a chip run; it catches what
 the chip's compiler refuses (unaligned blocks, unsupported primitives,
 programs that do not fit) at no chip time.
 
@@ -100,4 +100,33 @@ def test_chip_smoke_train_step_fits_one_chip(one_chip):
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert m.alias_size_in_bytes > 0          # the state is donated
+    assert total < V5E_HBM_BYTES, total
+
+
+@pytest.mark.parametrize("arch,batch,max_len", [("stablelm-1.6b", 8, 272),
+                                                ("mamba2-370m", 8, 256)])
+def test_serving_prefill_fits_one_chip(one_chip, arch, batch, max_len):
+    """The serving engine's one-call prefill (``jit_prefill``) at published
+    widths and depth, at the serve cells' batch and cache length: weights,
+    f32 caches and temporaries fit in one chip's HBM, and a KV cache is
+    donated into the call rather than held twice."""
+    from repro.configs import get_arch
+    from repro.models import transformer as T
+    from repro.serving.engine import ServingEngine
+    cfg = get_arch(arch).model
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: T.init_lm(jax.random.PRNGKey(0), cfg)))
+    caches = on_chip(jax.eval_shape(
+        lambda: T.init_caches(cfg, batch, max_len, jnp.float32)))
+    eng = ServingEngine(cfg, None, max_len=max_len)
+    tokens = _sds((batch, eng._prefill_len), jnp.int32, one_chip)
+    c = eng._prefill.lower(params, tokens, _sds((), jnp.int32, one_chip),
+                           caches).compile()
+    m = c.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    if cfg.family == "dense":
+        assert m.alias_size_in_bytes > 0
     assert total < V5E_HBM_BYTES, total
