@@ -60,6 +60,10 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         kw.update(num_experts=8, experts_per_token=2, moe_d_ff=64,
                   first_k_dense=min(cfg.first_k_dense, 1),
                   mtp_depth=min(cfg.mtp_depth, 1))
+        if cfg.n_group > 1:         # 4 groups of 2, the best 2 kept
+            kw.update(n_group=4, topk_group=2)
+        if cfg.experts_held:        # half the experts held here
+            kw.update(experts_held=4, expert_offset=0)
     if cfg.ssm_state:
         kw.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=16)  # d_inner=128 -> 8 heads
     if cfg.shared_attn_interval:

@@ -38,9 +38,22 @@ class ModelConfig:
     experts_per_token: int = 0
     moe_d_ff: int = 0
     first_k_dense: int = 0          # leading dense layers (deepseek)
-    router_type: str = "softmax"    # softmax | sigmoid (deepseek v3)
+    router_type: str = "softmax"    # softmax | sigmoid | noaux_tc (deepseek v3)
     capacity_factor: float = 1.25
     aux_loss_coef: float = 0.001
+    # noaux_tc: experts in n_group groups, the topk_group best groups kept
+    # (by the sum of each group's two best biased scores), the chosen
+    # experts' unbiased scores normalised and scaled
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    router_bias_scale: float = 0.0  # seed draw of e_score_correction_bias
+    # the experts this chip holds, [expert_offset, expert_offset +
+    # experts_held) of num_experts (0: all): the router keeps its
+    # num_experts outputs, and the layer computes every (token, held
+    # expert) pair with no capacity drop
+    experts_held: int = 0
+    expert_offset: int = 0
 
     # --- MLA (deepseek) ---
     q_lora_rank: int = 0
@@ -48,6 +61,13 @@ class ModelConfig:
     qk_rope_dim: int = 0
     qk_nope_dim: int = 0
     v_head_dim: int = 0
+    # YaRN rope (MLA): factor 0 is plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- SSM (mamba2) ---
     ssm_state: int = 0
@@ -76,7 +96,7 @@ class ModelConfig:
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
 
-    # source provenance (kept for DESIGN/EXPERIMENTS cross-reference)
+    # source provenance
     source: str = ""
 
     @property
@@ -161,7 +181,7 @@ class ModelConfig:
                                + self.num_shared_experts * per_exp
                                + D * self.num_experts)
             if self.mtp_depth:
-                mtp = self.mtp_depth * (a + self.num_experts * per_exp * 0 + mlp_params(self.moe_d_ff) * self.experts_per_token + 2 * D * D)
+                mtp = self.mtp_depth * (a + mlp_params(self.moe_d_ff) * self.experts_per_token + 2 * D * D)
                 total += self.mtp_depth * (a + self.num_experts * per_exp + 2 * D * D)
                 active += mtp
         elif self.family == "encdec":

@@ -6,12 +6,13 @@ prefill never materializes the (S x S) score matrix. The per-block body is
 wrapped in ``jax.checkpoint`` so the autodiff backward recomputes block
 scores instead of saving O(S^2) residuals. The GQA prefill path is the
 full path that also writes the prompt's rotated keys and values into the
-decode cache.
+decode cache; the MLA prefill writes the latent cache (``c_kv``, ``k_rope``)
+and attends one batch row at a time, so its scores stay one row's size.
 
 Decode attends a single new token against a KV cache laid out
 (batch, kv_heads, seq, head_dim) so the sharding resolver prefers
 head-sharding and falls back to split-KV sequence sharding when
-``kv_heads % TP != 0`` (flash-decoding pattern; see DESIGN.md §2).
+``kv_heads % TP != 0`` (flash-decoding pattern).
 """
 from __future__ import annotations
 
@@ -222,31 +223,54 @@ def init_mla(key, cfg, dtype=jnp.float32):
     }
 
 
+def mla_rope_freqs(cfg):
+    """The rope frequencies of MLA's decoupled key and query dims: YaRN's
+    when ``yarn_factor`` is set, else None (plain rope at ``rope_theta``)."""
+    if not cfg.yarn_factor:
+        return None
+    if L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) != \
+            L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim):
+        raise ValueError("YaRN with mscale != mscale_all_dim scales cos and "
+                         "sin; not supported")
+    return L.yarn_inv_freq(cfg.qk_rope_dim, cfg.rope_theta, cfg.yarn_factor,
+                           cfg.yarn_original_max, cfg.yarn_beta_fast,
+                           cfg.yarn_beta_slow)
+
+
+def mla_scale(cfg) -> float:
+    """Softmax scale: 1/sqrt(q head dim), times YaRN's mscale squared."""
+    m = (L.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+         if cfg.yarn_factor else 1.0)
+    return m * m * (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+
+
 def _mla_qkv(p, cfg, x, positions):
     B, S, _ = x.shape
     H = cfg.num_heads
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     dt = x.dtype
+    freqs = mla_rope_freqs(cfg)
     q = L.apply_rmsnorm(p["q_norm"], x @ p["wq_a"].astype(dt), cfg.norm_eps)
     q = (q @ p["wq_b"].astype(dt)).reshape(B, S, H, nope + rope)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta, freqs)
 
     kv = x @ p["wkv_a"].astype(dt)                    # (B,S,kvr+rope)
     c_kv = L.apply_rmsnorm(p["kv_norm"], kv[..., : cfg.kv_lora_rank], cfg.norm_eps)
     k_rope = kv[..., cfg.kv_lora_rank:][..., None, :]  # (B,S,1,rope)
-    k_rope = L.apply_rope(k_rope, positions, cfg.rope_theta)
+    k_rope = L.apply_rope(k_rope, positions, cfg.rope_theta, freqs)
     return q_nope, q_rope, c_kv, k_rope
 
 
-def apply_mla_full(p, cfg, x, positions):
-    B, S, _ = x.shape
+def _mla_attend(p, cfg, q_nope, q_rope, c_kv, k_rope, qpos,
+                block: int = KV_BLOCK):
+    """Causal attention of MLA's queries over the keys and values its
+    latent expands to (not absorbed: every position is a query).
+    Returns the heads' outputs (B,S,H*vd), before the out projection."""
+    B, S = c_kv.shape[:2]
     H = cfg.num_heads
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    dt = x.dtype
-    q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, cfg, x, positions)
-
-    kvb = p["wkv_b"].astype(dt).reshape(cfg.kv_lora_rank, H, nope + vd)
+    kvb = p["wkv_b"].astype(c_kv.dtype).reshape(cfg.kv_lora_rank, H, nope + vd)
     k_nope = jnp.einsum("bsc,chn->bshn", c_kv, kvb[..., :nope])
     v = jnp.einsum("bsc,chn->bshn", c_kv, kvb[..., nope:])
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (B, S, H, rope))], -1)
@@ -255,11 +279,53 @@ def apply_mla_full(p, cfg, x, positions):
     q = shard(q.transpose(0, 2, 1, 3), "batch", "heads", "seq_q", None)
     k = shard(k.transpose(0, 2, 1, 3), "batch", "heads", None, None)
     v = shard(v.transpose(0, 2, 1, 3), "batch", "heads", None, None)
+    out = blockwise_attention(q, k, v, qpos, qpos, block=block,
+                              scale=mla_scale(cfg))
+    return out.transpose(0, 2, 1, 3).reshape(B, S, H * vd)
+
+
+def apply_mla_full(p, cfg, x, positions):
+    dt = x.dtype
+    q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, cfg, x, positions)
     qpos = positions[0] if positions.ndim == 2 else positions
-    out = blockwise_attention(q, k, v, qpos, qpos,
-                              scale=(nope + rope) ** -0.5)
-    out = out.transpose(0, 2, 1, 3).reshape(B, S, H * vd)
+    out = _mla_attend(p, cfg, q_nope, q_rope, c_kv, k_rope, qpos)
     return out @ p["wo"].astype(dt)
+
+
+def _prefill_block(S: int, target: int = 512) -> int:
+    """A key block near ``target`` that tiles S with the least padding."""
+    n = -(-S // target)
+    per = -(-S // n)
+    return -(-per // 8) * 8
+
+
+def apply_mla_prefill(p, cfg, x, positions, cache):
+    """Causal MLA over every position of x that also writes its latent
+    (``c_kv``) and rotated rope key (``k_rope``) into the cache from
+    position 0: the cache ``apply_mla_decode`` leaves after S calls, up to
+    rounding. One batch row attends at a time (its scores are H x S x
+    block), so a long prompt batch fits beside the weights.
+
+    Returns (out (B,S,D), new_cache)."""
+    dt = x.dtype
+    S = x.shape[1]
+    q_nope, q_rope, c_kv, k_rope = _mla_qkv(p, cfg, x, positions)
+    qpos = positions[0] if positions.ndim == 2 else positions
+    block = _prefill_block(S)
+
+    def row(xs):
+        qn, qr, ck, kr = (a[None] for a in xs)
+        return _mla_attend(p, cfg, qn, qr, ck, kr, qpos, block)[0]
+
+    out = jax.lax.map(row, (q_nope, q_rope, c_kv, k_rope))
+    c_c = jax.lax.dynamic_update_slice(
+        cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, 0, 0))
+    r_c = jax.lax.dynamic_update_slice(
+        cache["k_rope"], k_rope[:, :, 0, :].astype(cache["k_rope"].dtype),
+        (0, 0, 0))
+    c_c = shard(c_c, "batch", "kv_seq", None)
+    r_c = shard(r_c, "batch", "kv_seq", None)
+    return out @ p["wo"].astype(dt), {"c_kv": c_c, "k_rope": r_c}
 
 
 def init_mla_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
@@ -295,7 +361,7 @@ def apply_mla_decode(p, cfg, x, cache, index):
                    c_kv.astype(jnp.float32))
     s += jnp.einsum("bshr,btr->bhst", q_rope.astype(jnp.float32),
                     k_rope.astype(jnp.float32))
-    s *= (nope + rope) ** -0.5
+    s *= mla_scale(cfg)
     Smax = c_kv.shape[1]
     valid = jnp.arange(Smax)[None, None, None, :] <= index
     s = jnp.where(valid, s, -1e30)

@@ -9,6 +9,7 @@ the TPU-idiomatic MaxText pattern.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable
 
 import jax
@@ -105,10 +106,38 @@ def _rope_tables(positions, dim: int, theta: float):
     return jnp.cos(ang), jnp.sin(ang)
 
 
-def apply_rope(x, positions, theta: float = 10_000.0):
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's rotary frequencies (arXiv:2309.00071, as DeepSeek-V3 applies
+    them): the dims that turn more than ``beta_fast`` times over
+    ``original_max`` positions keep their frequency, those that turn fewer
+    than ``beta_slow`` times are divided by ``factor``, and a linear ramp
+    blends the dims between."""
+    def dim_at(rot):
+        return dim * math.log(original_max / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    lo = max(math.floor(dim_at(beta_fast)), 0)
+    hi = min(math.ceil(dim_at(beta_slow)), dim - 1)
+    base = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - lo)
+                   / max(hi - lo, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return (base / factor * (1.0 - keep) + base * keep).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x, positions, theta: float = 10_000.0, inv_freq=None):
+    """x: (..., seq, heads, head_dim); positions: (..., seq). ``inv_freq``
+    (head_dim/2 frequencies, e.g. ``yarn_inv_freq``) replaces theta's."""
     dim = x.shape[-1]
-    cos, sin = _rope_tables(positions, dim, theta)     # (..., seq, dim/2)
+    if inv_freq is None:
+        cos, sin = _rope_tables(positions, dim, theta)  # (..., seq, dim/2)
+    else:
+        ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
     cos = cos[..., None, :]                            # broadcast over heads
     sin = sin[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

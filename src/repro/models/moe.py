@@ -1,6 +1,6 @@
 """Mixture-of-Experts with expert parallelism.
 
-TPU adaptation (DESIGN.md §2): instead of the CUDA-style token-permutation
+TPU adaptation: instead of the CUDA-style token-permutation
 or the classic GShard one-hot dispatch einsum — whose (tokens x E x capacity)
 one-hot tensors add O(tokens^2) *fake* FLOPs and O(GB) temporaries — we use a
 **sort-based capacity-bucketed dispatch**: tokens are argsorted by expert id,
@@ -12,16 +12,26 @@ Expert parallelism runs under ``shard_map``: activations arrive replicated
 across the ``model`` axis (standard TP layout), each shard computes its
 E/TP experts over the full local batch, and partial outputs are ``psum``-ed
 over ``model``. (The §Perf hillclimb replaces replicated activations + psum
-with sequence-sharded activations + all-to-all dispatch; see EXPERIMENTS.md.)
+with sequence-sharded activations + all-to-all dispatch: ``moe_a2a``.)
 
 FSDP-compatible: if expert weights arrive d_model-sharded over ``data``
 (DeepSeek-671B config), they are all-gathered per layer inside the shard_map
 — exactly the FSDP weight-gather pattern.
+
+A layer told that it holds ``experts_held`` experts from ``expert_offset``
+(one chip's share under expert parallelism) routes over all
+``num_experts`` and computes only its own experts' part of the result, for
+every (token, held expert) pair: no capacity drop at any load. Its pairs
+are sorted by expert and each expert runs as many row tiles as it has
+rows, so the cost follows the routed rows and an expert that got no token
+is never read. That path is for inference (its loops have a trip count
+known only on the device, which reverse-mode differentiation refuses).
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,26 +41,64 @@ from repro.models import layers as L
 from repro.sharding.ctx import axis_ctx, current_strategy, shard
 
 
+def held_experts(cfg) -> Tuple[int, int]:
+    """(first expert, count) of the experts this layer holds."""
+    if cfg.experts_held:
+        return cfg.expert_offset, cfg.experts_held
+    return 0, cfg.num_experts
+
+
 def init_moe(key, cfg, dtype=jnp.float32):
     D, E, F = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    e0, n = held_experts(cfg)
     ks = jax.random.split(key, 6)
+    # every expert's key is drawn, so a held expert's weights are those it
+    # has in the whole layer
+    held = lambda k: jax.random.split(k, E)[e0:e0 + n]
     p = {
         "router": L.dense_init(ks[0], D, E, jnp.float32),  # router kept fp32
         "experts": {
-            "gate": jax.vmap(lambda k: L.dense_init(k, D, F, dtype))(jax.random.split(ks[1], E)),
-            "up": jax.vmap(lambda k: L.dense_init(k, D, F, dtype))(jax.random.split(ks[2], E)),
-            "down": jax.vmap(lambda k: L.dense_init(k, F, D, dtype))(jax.random.split(ks[3], E)),
+            "gate": jax.vmap(lambda k: L.dense_init(k, D, F, dtype))(held(ks[1])),
+            "up": jax.vmap(lambda k: L.dense_init(k, D, F, dtype))(held(ks[2])),
+            "down": jax.vmap(lambda k: L.dense_init(k, F, D, dtype))(held(ks[3])),
         },
     }
     if cfg.num_shared_experts:
         p["shared"] = L.init_mlp(ks[4], D, F * cfg.num_shared_experts, "swiglu", dtype)
+    if cfg.router_type == "noaux_tc":
+        # e_score_correction_bias: moves selection, not the weights
+        p["router_bias"] = jax.random.normal(ks[5], (E,), jnp.float32) \
+            * cfg.router_bias_scale
     return p
+
+
+def _noaux_tc(p, cfg, logits):
+    """DeepSeek-V3's routing: sigmoid scores; selection by score + bias
+    within the ``topk_group`` groups whose two best biased scores sum
+    highest; weights the chosen experts' unbiased scores, normalised, times
+    ``routed_scaling_factor``."""
+    E, G, k = cfg.num_experts, cfg.n_group, cfg.experts_per_token
+    scores = jax.nn.sigmoid(logits)
+    biased = scores + p["router_bias"]
+    if G > 1:
+        grp = biased.reshape(biased.shape[:-1] + (G, E // G))
+        best = jnp.sum(jax.lax.top_k(grp, min(2, E // G))[0], -1)   # (..,G)
+        _, gidx = jax.lax.top_k(best, cfg.topk_group)
+        keep = jnp.sum(jax.nn.one_hot(gidx, G, dtype=jnp.int32), -2) > 0
+        biased = jnp.where(jnp.repeat(keep, E // G, axis=-1), biased,
+                           -jnp.inf)
+    _, idx = jax.lax.top_k(biased, k)
+    w = jnp.take_along_axis(scores, idx, -1)
+    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scaling_factor, idx
 
 
 def _route(p, cfg, x):
     """Returns (weights (B,S,k), idx (B,S,k), aux_loss scalar)."""
     logits = (x.astype(jnp.float32) @ p["router"])          # (B,S,E)
-    if cfg.router_type == "sigmoid":                        # deepseek-v3
+    if cfg.router_type == "noaux_tc":                       # deepseek-v3
+        w, idx = _noaux_tc(p, cfg, logits)
+    elif cfg.router_type == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         w, idx = jax.lax.top_k(scores, cfg.experts_per_token)
         w = w / jnp.maximum(jnp.sum(w, -1, keepdims=True), 1e-9)
@@ -65,6 +113,50 @@ def _route(p, cfg, x):
     pbar = jnp.mean(probs, axis=(0, 1))
     aux = E * jnp.sum(f * pbar) * cfg.aux_loss_coef
     return w.astype(x.dtype), idx, aux
+
+
+def _held_tile(tokens: int, k: int, num_experts: int) -> int:
+    """Rows of one tile of the held-expert path: an expert's expected rows
+    and a quarter more, 8-aligned (an expert with more runs more tiles)."""
+    return max(8, -(-math.ceil(1.25 * tokens * k / num_experts) // 8) * 8)
+
+
+def _held_expert_compute(x2d, idx2d, w2d, valid, ex, e0, tile):
+    """Every (token, held expert) pair of experts [e0, e0 + El), none
+    dropped. x2d: (T, D); idx2d/w2d: (T, k); valid: (T,) bool or None
+    (False: a padding row, routed nowhere); ex: gate/up (El, D, F), down
+    (El, F, D). Returns ((T, D) float32 partial output, (El,) int32 rows
+    each held expert computed)."""
+    T, D = x2d.shape
+    k = idx2d.shape[1]
+    El = ex["gate"].shape[0]
+    N = T * k
+    e = idx2d.reshape(N) - e0
+    held = (e >= 0) & (e < El)
+    if valid is not None:
+        held &= jnp.repeat(valid, k)
+    key = jnp.where(held, e, El)                          # not held: last
+    order = jnp.argsort(key)                              # stable
+    stok = (order // k).astype(jnp.int32)
+    sw = w2d.reshape(N)[order].astype(jnp.float32)
+    starts = jnp.searchsorted(key[order], jnp.arange(El + 1), side="left")
+    load = (starts[1:] - starts[:-1]).astype(jnp.int32)
+
+    def expert(y, xs):
+        s0, n, g, u, d = xs
+
+        def one_tile(i, y):
+            r = s0 + i * tile + jnp.arange(tile)
+            w = jnp.where(r < s0 + n, sw[jnp.minimum(r, N - 1)], 0.0)
+            tok = stok[jnp.minimum(r, N - 1)]
+            xin = x2d[tok]
+            h = jax.nn.silu(xin @ g) * (xin @ u)
+            return y.at[tok].add((h @ d).astype(jnp.float32) * w[:, None])
+        return jax.lax.fori_loop(0, (n + tile - 1) // tile, one_tile, y), None
+
+    y, _ = jax.lax.scan(expert, jnp.zeros((T, D), jnp.float32),
+                        (starts[:-1], load, ex["gate"], ex["up"], ex["down"]))
+    return y, load
 
 
 def _capacity(tokens: int, k: int, num_experts: int, cf: float) -> int:
@@ -208,10 +300,35 @@ def _apply_moe_a2a(cfg, mesh, x2d, idx2d, w2d, ex):
     return shard(y4.reshape(T, D_model), "batch", None)
 
 
+def apply_moe_held(p, cfg, x, valid: Optional[jax.Array] = None):
+    """The held experts' part plus the shared expert, every (token, held
+    expert) pair computed. x: (B,S,D); valid: (B,S) bool or None (False:
+    padding, routed nowhere). Returns (out (B,S,D), rows per held expert
+    (experts_held,) int32)."""
+    w, idx, _ = _route(p, cfg, x)
+    return _apply_held(p, cfg, x, w, idx, valid)
+
+
+def _apply_held(p, cfg, x, w, idx, valid):
+    B, S, D = x.shape
+    x2d = x.reshape(B * S, D)
+    e0, _ = held_experts(cfg)
+    tile = _held_tile(B * S, cfg.experts_per_token, cfg.num_experts)
+    y, load = _held_expert_compute(
+        x2d, idx.reshape(B * S, -1), w.reshape(B * S, -1),
+        None if valid is None else valid.reshape(B * S), p["experts"], e0,
+        tile)
+    if "shared" in p:
+        y = y + L.apply_mlp(p["shared"], x2d, "swiglu").astype(jnp.float32)
+    return y.reshape(B, S, D).astype(x.dtype), load
+
+
 def apply_moe(p, cfg, x) -> Tuple[jax.Array, jax.Array]:
     """x: (B,S,D) -> (out (B,S,D), aux_loss)."""
     B, S, D = x.shape
     w, idx, aux = _route(p, cfg, x)
+    if cfg.experts_held:
+        return _apply_held(p, cfg, x, w, idx, None)[0], aux
     x2d = x.reshape(B * S, D)
     idx2d = idx.reshape(B * S, -1)
     w2d = w.reshape(B * S, -1)

@@ -8,7 +8,9 @@ the scanned layer body.
 ``apply_lm``         : full-sequence forward -> (logits, aux)  [train]
 ``apply_lm_decode``  : one-token forward with caches -> (logits, new_caches)
 ``apply_lm_prefill`` : padded-prompt forward that writes the decode caches
-                       -> (last-position logits, caches)  [dense, ssm]
+                       -> (last-position logits, caches)  [dense, ssm, moe]
+``apply_moe_prefill``: the same for moe, with each layer's rows per held
+                       expert
 ``init_lm``/``init_caches`` build the matching parameter / cache pytrees.
 """
 from __future__ import annotations
@@ -544,7 +546,7 @@ def apply_lm_decode(params, cfg, token, caches, index):
 
 # the families ``apply_lm_prefill`` covers; the others prefill through
 # ``apply_lm_decode``, one call a prompt token
-PREFILL_FAMILIES = ("dense", "ssm")
+PREFILL_FAMILIES = ("dense", "ssm", "moe")
 
 
 def prefill_len(cfg, max_len: int) -> int:
@@ -566,6 +568,8 @@ def apply_lm_prefill(params, cfg, tokens, length, caches):
     masking keeps every position below ``length`` exact; cache entries at
     or past it hold padding, which decode overwrites before it reads them.
     """
+    if cfg.family == "moe":
+        return apply_moe_prefill(params, cfg, tokens, length, caches)[:2]
     cdt = _cdt(cfg)
     B, Sp = tokens.shape
     h = L.apply_embed({"table": params["embed"]["table"]}, tokens).astype(cdt)
@@ -598,3 +602,50 @@ def apply_lm_prefill(params, cfg, tokens, length, caches):
     h = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
     h = L.apply_rmsnorm(params["final_norm"], h, cfg.norm_eps)
     return _head(params, cfg, h), {"layers": new}
+
+
+def apply_moe_prefill(params, cfg, tokens, length, caches):
+    """``apply_lm_prefill`` for family moe (MLA or GQA attention): the
+    leading dense layers, then the expert layers, each computing every
+    (token, held expert) pair of the prompt's positions (padding is routed
+    nowhere). Returns (logits (B,1,V), caches, rows per held expert of each
+    expert layer (layers, experts held) int32)."""
+    cdt = _cdt(cfg)
+    B, Sp = tokens.shape
+    h = L.apply_embed({"table": params["embed"]["table"]}, tokens).astype(cdt)
+    h = shard(h, "batch", None, None)
+    positions = jnp.broadcast_to(jnp.arange(Sp, dtype=jnp.int32)[None], (B, Sp))
+    valid = positions < length
+    attend = (A.apply_mla_prefill if cfg.attention == "mla"
+              else A.apply_attention_prefill)
+
+    def attn(lp, hh, cache):
+        a, nc = attend(lp["attn"], cfg,
+                       L.apply_rmsnorm(lp["ln1"], hh, cfg.norm_eps),
+                       positions, cache)
+        return hh + a, nc
+
+    new = {}
+    if cfg.first_k_dense:
+        def dstep(hh, xs):
+            lp, cache = xs
+            hh, nc = attn(lp, hh, cache)
+            hh = hh + L.apply_mlp(lp["mlp"],
+                                  L.apply_rmsnorm(lp["ln2"], hh, cfg.norm_eps),
+                                  cfg.act)
+            return shard(hh, "batch", None, None), nc
+        h, new["dense_layers"] = jax.lax.scan(
+            dstep, h, (params["dense_layers"], caches["dense_layers"]))
+
+    def mstep(hh, xs):
+        lp, cache = xs
+        hh, nc = attn(lp, hh, cache)
+        y, load = M.apply_moe_held(
+            lp["moe"], cfg, L.apply_rmsnorm(lp["ln2"], hh, cfg.norm_eps), valid)
+        return shard(hh + y, "batch", None, None), (nc, load)
+    h, (new["layers"], load) = jax.lax.scan(
+        mstep, h, (params["layers"], caches["layers"]))
+
+    h = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
+    h = L.apply_rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    return _head(params, cfg, h), new, load

@@ -6,6 +6,10 @@ O(1) recurrent state for SSM/hybrid) behind one request-batch API. The
 ``long_500k`` dry-run cells lower at production scale. A prompt of the
 families in ``T.PREFILL_FAMILIES`` fills the caches in one call; the other
 families feed it through the decode step, one call a prompt token.
+
+For family moe the prefill call also returns how many prompt rows each
+held expert of each expert layer computed. They come back with the
+logits and are counted in ``METRICS`` (docs/observability.md).
 """
 from __future__ import annotations
 
@@ -17,9 +21,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.obs.metrics import MetricsRegistry
 from repro.models import transformer as T
 
 span = jax.profiler.TraceAnnotation
+
+# the serving engines' counters, in this process
+METRICS = MetricsRegistry("serving")
+# buckets of a prefill's largest held-expert load over the mean held load
+LOAD_RATIO_BUCKETS = (1.0, 1.1, 1.25, 1.5, 2.0, 3.0, 5.0, 10.0)
 
 
 @dataclass
@@ -34,6 +44,8 @@ class GenerationResult:
     # device calls the prefill took: 1 through ``jit_prefill``, else one
     # decode call a prompt token
     prefill_calls: int = 0
+    # family moe: the prefill's rows per held expert, [expert layer][expert]
+    expert_load: Optional[list] = None
 
 
 class ServingEngine:
@@ -51,8 +63,11 @@ class ServingEngine:
         self._step = jax.jit(decode_step)
         self._prefill = None
         if cfg.family in T.PREFILL_FAMILIES:
+            fn = (T.apply_moe_prefill if cfg.family == "moe"
+                  else T.apply_lm_prefill)
+
             def prefill(p, t, n, c):
-                return T.apply_lm_prefill(p, cfg, t, n, c)
+                return fn(p, cfg, t, n, c)
 
             # module ``jit_prefill``; one program per (batch, max_len): the
             # prompt is padded to ``_prefill_len`` and its length is traced
@@ -81,15 +96,16 @@ class ServingEngine:
                 # padded on the host: a device pad would compile once per P
                 padded = np.zeros((B, self._prefill_len), np.int32)
                 padded[:, :P] = np.asarray(prompts)
-                logits, caches = self._prefill(self.params, padded,
-                                               np.int32(P), caches)
+                logits, caches, *load = self._prefill(
+                    self.params, padded, np.int32(P), caches)
                 prefill_calls = 1
             else:
+                load = []
                 for i in range(P):          # prefill via the decode path
                     logits, caches = self._step(
                         self.params, prompts[:, i:i + 1], caches, jnp.int32(i))
                 prefill_calls = P
-            jax.block_until_ready((logits, caches))
+            jax.block_until_ready((logits, caches, load))
             prefill_s = time.time() - t0
         prompt_logits = logits[:, -1]
 
@@ -113,7 +129,25 @@ class ServingEngine:
             decode_s = time.time() - t0
         with span("serve.fetch"):
             tokens = gen.tolist()
+            expert_load = count_expert_load(load[0]) if load else None
         return GenerationResult(
             tokens=tokens, prefill_s=prefill_s, decode_s=decode_s,
             tokens_per_s=B * gen.shape[1] / max(decode_s, 1e-9),
-            prompt_logits=prompt_logits, prefill_calls=prefill_calls)
+            prompt_logits=prompt_logits, prefill_calls=prefill_calls,
+            expert_load=expert_load)
+
+
+def count_expert_load(load) -> list:
+    """Count one prefill's rows per (expert layer, held expert) in
+    ``METRICS``: the counter ``prefill_expert_rows`` and the histogram
+    ``prefill_expert_load_max`` of the largest load over the mean."""
+    load = np.asarray(load)
+    for (layer, expert), n in np.ndenumerate(load):
+        METRICS.counter("prefill_expert_rows", layer=str(layer),
+                        expert=str(expert)).inc(int(n))
+    mean = load.mean()
+    if mean > 0:
+        METRICS.histogram("prefill_expert_load_max",
+                          buckets=LOAD_RATIO_BUCKETS).observe(
+            float(load.max() / mean))
+    return load.tolist()
