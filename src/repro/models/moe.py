@@ -22,10 +22,19 @@ A layer told that it holds ``experts_held`` experts from ``expert_offset``
 (one chip's share under expert parallelism) routes over all
 ``num_experts`` and computes only its own experts' part of the result, for
 every (token, held expert) pair: no capacity drop at any load. Its pairs
-are sorted by expert and each expert runs as many row tiles as it has
-rows, so the cost follows the routed rows and an expert that got no token
-is never read. That path is for inference (its loops have a trip count
-known only on the device, which reverse-mode differentiation refuses).
+are sorted by expert and one loop runs the row tiles that have rows, each
+expert as many as it needs, in ascending expert order. A tile reads its
+expert's gate, up and down weights in place, by (layer, expert), from the
+stacked params at its matmuls: the expert layer scans of
+``apply_moe_decode`` and ``apply_moe_prefill`` leave the held experts out
+of their ``xs`` and pass the whole stack and the layer index to
+``apply_moe_held_at`` (``apply_moe_held`` and ``apply_moe`` pass one
+layer's experts as a stack of one). So the cost
+follows the routed rows and an expert that got no token is never read
+(the slice index changes from one trip to the next, so the compiler
+cannot hoist the read out of the loop). That path is for inference (its
+loop has a trip count known only on the device, which reverse-mode
+differentiation refuses).
 """
 from __future__ import annotations
 
@@ -121,15 +130,16 @@ def _held_tile(tokens: int, k: int, num_experts: int) -> int:
     return max(8, -(-math.ceil(1.25 * tokens * k / num_experts) // 8) * 8)
 
 
-def _held_expert_compute(x2d, idx2d, w2d, valid, ex, e0, tile):
+def _held_expert_compute(x2d, idx2d, w2d, valid, ex, e0, tile, layer):
     """Every (token, held expert) pair of experts [e0, e0 + El), none
     dropped. x2d: (T, D); idx2d/w2d: (T, k); valid: (T,) bool or None
-    (False: a padding row, routed nowhere); ex: gate/up (El, D, F), down
-    (El, F, D). Returns ((T, D) float32 partial output, (El,) int32 rows
-    each held expert computed)."""
+    (False: a padding row, routed nowhere); ex: gate/up (layers, El, D, F),
+    down (layers, El, F, D), the held experts of every expert layer, of
+    which layer ``layer`` (may be traced) is read. Returns ((T, D) float32
+    partial output, (El,) int32 rows each held expert computed)."""
     T, D = x2d.shape
     k = idx2d.shape[1]
-    El = ex["gate"].shape[0]
+    El = ex["gate"].shape[1]
     N = T * k
     e = idx2d.reshape(N) - e0
     held = (e >= 0) & (e < El)
@@ -141,21 +151,26 @@ def _held_expert_compute(x2d, idx2d, w2d, valid, ex, e0, tile):
     sw = w2d.reshape(N)[order].astype(jnp.float32)
     starts = jnp.searchsorted(key[order], jnp.arange(El + 1), side="left")
     load = (starts[1:] - starts[:-1]).astype(jnp.int32)
+    tiles = (load + tile - 1) // tile
+    ends = jnp.cumsum(tiles)      # expert e runs trips [ends - tiles, ends)
 
-    def expert(y, xs):
-        s0, n, g, u, d = xs
+    def weight(name, ei):
+        a = ex[name]
+        return jax.lax.dynamic_slice(a, (layer, ei, 0, 0),
+                                     (1, 1) + a.shape[2:])[0, 0]
 
-        def one_tile(i, y):
-            r = s0 + i * tile + jnp.arange(tile)
-            w = jnp.where(r < s0 + n, sw[jnp.minimum(r, N - 1)], 0.0)
-            tok = stok[jnp.minimum(r, N - 1)]
-            xin = x2d[tok]
-            h = jax.nn.silu(xin @ g) * (xin @ u)
-            return y.at[tok].add((h @ d).astype(jnp.float32) * w[:, None])
-        return jax.lax.fori_loop(0, (n + tile - 1) // tile, one_tile, y), None
+    def one_tile(i, y):
+        ei = jnp.searchsorted(ends, i, side="right")
+        r = starts[ei] + (i - ends[ei] + tiles[ei]) * tile + jnp.arange(tile)
+        w = jnp.where(r < starts[ei + 1], sw[jnp.minimum(r, N - 1)], 0.0)
+        tok = stok[jnp.minimum(r, N - 1)]
+        xin = x2d[tok]
+        h = jax.nn.silu(xin @ weight("gate", ei)) * (xin @ weight("up", ei))
+        return y.at[tok].add(
+            (h @ weight("down", ei)).astype(jnp.float32) * w[:, None])
 
-    y, _ = jax.lax.scan(expert, jnp.zeros((T, D), jnp.float32),
-                        (starts[:-1], load, ex["gate"], ex["up"], ex["down"]))
+    y = jax.lax.fori_loop(0, ends[-1], one_tile,
+                          jnp.zeros((T, D), jnp.float32))
     return y, load
 
 
@@ -302,22 +317,36 @@ def _apply_moe_a2a(cfg, mesh, x2d, idx2d, w2d, ex):
 
 def apply_moe_held(p, cfg, x, valid: Optional[jax.Array] = None):
     """The held experts' part plus the shared expert, every (token, held
-    expert) pair computed. x: (B,S,D); valid: (B,S) bool or None (False:
-    padding, routed nowhere). Returns (out (B,S,D), rows per held expert
-    (experts_held,) int32)."""
+    expert) pair computed, from one layer's params ``p``. x: (B,S,D);
+    valid: (B,S) bool or None (False: padding, routed nowhere). Returns
+    (out (B,S,D), rows per held expert (experts_held,) int32)."""
+    return apply_moe_held_at(p, cfg, x, _one_layer(p["experts"]), 0, valid)
+
+
+def apply_moe_held_at(p, cfg, x, experts, layer,
+                      valid: Optional[jax.Array] = None):
+    """``apply_moe_held`` for layer ``layer`` (may be traced) of
+    ``experts``, the held experts of every expert layer (gate/up (layers,
+    experts held, D, F), down (layers, experts held, F, D)), read in place
+    by the tiles that use them. p: the layer's router and shared expert."""
     w, idx, _ = _route(p, cfg, x)
-    return _apply_held(p, cfg, x, w, idx, valid)
+    return _apply_held(p, cfg, x, w, idx, valid, experts, layer)
 
 
-def _apply_held(p, cfg, x, w, idx, valid):
+def _one_layer(ex):
+    """One layer's held experts as a stack of one layer."""
+    return jax.tree.map(lambda a: a[None], ex)
+
+
+def _apply_held(p, cfg, x, w, idx, valid, experts, layer):
     B, S, D = x.shape
     x2d = x.reshape(B * S, D)
     e0, _ = held_experts(cfg)
     tile = _held_tile(B * S, cfg.experts_per_token, cfg.num_experts)
     y, load = _held_expert_compute(
         x2d, idx.reshape(B * S, -1), w.reshape(B * S, -1),
-        None if valid is None else valid.reshape(B * S), p["experts"], e0,
-        tile)
+        None if valid is None else valid.reshape(B * S), experts, e0, tile,
+        layer)
     if "shared" in p:
         y = y + L.apply_mlp(p["shared"], x2d, "swiglu").astype(jnp.float32)
     return y.reshape(B, S, D).astype(x.dtype), load
@@ -328,7 +357,8 @@ def apply_moe(p, cfg, x) -> Tuple[jax.Array, jax.Array]:
     B, S, D = x.shape
     w, idx, aux = _route(p, cfg, x)
     if cfg.experts_held:
-        return _apply_held(p, cfg, x, w, idx, None)[0], aux
+        return _apply_held(p, cfg, x, w, idx, None,
+                           _one_layer(p["experts"]), 0)[0], aux
     x2d = x.reshape(B * S, D)
     idx2d = idx.reshape(B * S, -1)
     w2d = w.reshape(B * S, -1)

@@ -9,8 +9,8 @@ the scanned layer body.
 ``apply_lm_decode``  : one-token forward with caches -> (logits, new_caches)
 ``apply_lm_prefill`` : padded-prompt forward that writes the decode caches
                        -> (last-position logits, caches)  [dense, ssm, moe]
-``apply_moe_prefill``: the same for moe, with each layer's rows per held
-                       expert
+``apply_moe_decode`` / ``apply_moe_prefill``: the same for moe, with each
+                       expert layer's rows per held expert
 ``init_lm``/``init_caches`` build the matching parameter / cache pytrees.
 """
 from __future__ import annotations
@@ -428,10 +428,12 @@ def apply_lm_decode(params, cfg, token, caches, index):
 
     Returns (logits (B,1,V), new_caches).
     """
+    fam = cfg.family
+    if fam == "moe":
+        return apply_moe_decode(params, cfg, token, caches, index)[:2]
     cdt = _cdt(cfg)
     B = token.shape[0]
     h = L.apply_embed({"table": params["embed"]["table"]}, token).astype(cdt)
-    fam = cfg.family
 
     if fam in ("dense", "vlm"):
         def step(hh, xs):
@@ -446,36 +448,6 @@ def apply_lm_decode(params, cfg, token, caches, index):
             return hh, nc
         h, new = jax.lax.scan(step, h, (params["layers"], caches["layers"]))
         caches = {"layers": new}
-    elif fam == "moe":
-        dec = (A.apply_mla_decode if cfg.attention == "mla"
-               else A.apply_attention_decode)
-        new_caches = {}
-        if cfg.first_k_dense:
-            def dstep(hh, xs):
-                lp, cache = xs
-                a, nc = dec(lp["attn"], cfg,
-                            L.apply_rmsnorm(lp["ln1"], hh, cfg.norm_eps),
-                            cache, index)
-                hh = hh + a
-                hh = hh + L.apply_mlp(lp["mlp"],
-                                      L.apply_rmsnorm(lp["ln2"], hh, cfg.norm_eps),
-                                      cfg.act)
-                return hh, nc
-            h, newd = jax.lax.scan(dstep, h, (params["dense_layers"],
-                                              caches["dense_layers"]))
-            new_caches["dense_layers"] = newd
-
-        def mstep(hh, xs):
-            lp, cache = xs
-            a, nc = dec(lp["attn"], cfg,
-                        L.apply_rmsnorm(lp["ln1"], hh, cfg.norm_eps), cache, index)
-            hh = hh + a
-            y, _ = M.apply_moe(lp["moe"], cfg,
-                               L.apply_rmsnorm(lp["ln2"], hh, cfg.norm_eps))
-            return hh + y, nc
-        h, newm = jax.lax.scan(mstep, h, (params["layers"], caches["layers"]))
-        new_caches["layers"] = newm
-        caches = new_caches
     elif fam == "ssm":
         def step(hh, xs):
             lp, cache = xs
@@ -538,6 +510,63 @@ def apply_lm_decode(params, cfg, token, caches, index):
 
     h = L.apply_rmsnorm(params["final_norm"], h, cfg.norm_eps)
     return _head(params, cfg, h), caches
+
+
+def _split_experts(layers):
+    """An expert layer scan's per-layer params without the held experts,
+    and the held experts' (layers, experts held, ...) stack. The scan
+    slices no expert: the held path reads one in place by (layer, expert)
+    where a tile of its rows uses it."""
+    moe = dict(layers["moe"])
+    experts = moe.pop("experts")
+    return dict(layers, moe=moe), experts
+
+
+def apply_moe_decode(params, cfg, token, caches, index):
+    """``apply_lm_decode`` for family moe. Returns (logits (B,1,V),
+    new_caches, rows per held expert of each expert layer (layers,
+    experts held) int32, or None on the all-experts path)."""
+    cdt = _cdt(cfg)
+    h = L.apply_embed({"table": params["embed"]["table"]}, token).astype(cdt)
+    dec = (A.apply_mla_decode if cfg.attention == "mla"
+           else A.apply_attention_decode)
+    new_caches = {}
+    if cfg.first_k_dense:
+        def dstep(hh, xs):
+            lp, cache = xs
+            a, nc = dec(lp["attn"], cfg,
+                        L.apply_rmsnorm(lp["ln1"], hh, cfg.norm_eps),
+                        cache, index)
+            hh = hh + a
+            hh = hh + L.apply_mlp(lp["mlp"],
+                                  L.apply_rmsnorm(lp["ln2"], hh, cfg.norm_eps),
+                                  cfg.act)
+            return hh, nc
+        h, newd = jax.lax.scan(dstep, h, (params["dense_layers"],
+                                          caches["dense_layers"]))
+        new_caches["dense_layers"] = newd
+
+    # the all-experts path consumes every expert of its layer: it keeps
+    # them in the scan's xs
+    layers, experts = (_split_experts(params["layers"]) if cfg.experts_held
+                       else (params["layers"], None))
+
+    def mstep(hh, xs):
+        lp, cache, layer = xs
+        a, nc = dec(lp["attn"], cfg,
+                    L.apply_rmsnorm(lp["ln1"], hh, cfg.norm_eps), cache, index)
+        hh = hh + a
+        x = L.apply_rmsnorm(lp["ln2"], hh, cfg.norm_eps)
+        if experts is None:
+            return hh + M.apply_moe(lp["moe"], cfg, x)[0], (nc, None)
+        y, load = M.apply_moe_held_at(lp["moe"], cfg, x, experts, layer)
+        return hh + y, (nc, load)
+    n_moe = cfg.num_layers - cfg.first_k_dense
+    h, (new_caches["layers"], load) = jax.lax.scan(
+        mstep, h, (layers, caches["layers"], jnp.arange(n_moe)))
+
+    h = L.apply_rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    return _head(params, cfg, h), new_caches, load
 
 
 # ---------------------------------------------------------------------------
@@ -637,14 +666,20 @@ def apply_moe_prefill(params, cfg, tokens, length, caches):
         h, new["dense_layers"] = jax.lax.scan(
             dstep, h, (params["dense_layers"], caches["dense_layers"]))
 
+    # the expert layers compute every (token, held expert) pair: the held
+    # path, at every experts_held
+    layers, experts = _split_experts(params["layers"])
+
     def mstep(hh, xs):
-        lp, cache = xs
+        lp, cache, layer = xs
         hh, nc = attn(lp, hh, cache)
-        y, load = M.apply_moe_held(
-            lp["moe"], cfg, L.apply_rmsnorm(lp["ln2"], hh, cfg.norm_eps), valid)
+        y, load = M.apply_moe_held_at(
+            lp["moe"], cfg, L.apply_rmsnorm(lp["ln2"], hh, cfg.norm_eps),
+            experts, layer, valid)
         return shard(hh + y, "batch", None, None), (nc, load)
+    n_moe = cfg.num_layers - cfg.first_k_dense
     h, (new["layers"], load) = jax.lax.scan(
-        mstep, h, (params["layers"], caches["layers"]))
+        mstep, h, (layers, caches["layers"], jnp.arange(n_moe)))
 
     h = jax.lax.dynamic_slice_in_dim(h, length - 1, 1, axis=1)
     h = L.apply_rmsnorm(params["final_norm"], h, cfg.norm_eps)

@@ -9,7 +9,10 @@ families feed it through the decode step, one call a prompt token.
 
 For family moe the prefill call also returns how many prompt rows each
 held expert of each expert layer computed. They come back with the
-logits and are counted in ``METRICS`` (docs/observability.md).
+logits and are counted in ``METRICS`` (docs/observability.md). A decode
+call of a layer that holds a share of the experts returns its rows per
+held expert too; ``generate`` keeps them on the device and fetches them
+once, with the tokens, to count the held experts each call read.
 """
 from __future__ import annotations
 
@@ -56,8 +59,12 @@ class ServingEngine:
         self.max_len = max_len
         self.cache_dtype = cache_dtype
 
+        # (logits, caches, rows per held expert of each expert layer, or
+        # None where no layer holds a share)
         def decode_step(p, t, c, i):
-            return T.apply_lm_decode(p, cfg, t, c, i)
+            if cfg.family == "moe":
+                return T.apply_moe_decode(p, cfg, t, c, i)
+            return (*T.apply_lm_decode(p, cfg, t, c, i), None)
 
         # named, so that its module reads ``jit_decode_step`` in a trace
         self._step = jax.jit(decode_step)
@@ -102,7 +109,7 @@ class ServingEngine:
             else:
                 load = []
                 for i in range(P):          # prefill via the decode path
-                    logits, caches = self._step(
+                    logits, caches, _ = self._step(
                         self.params, prompts[:, i:i + 1], caches, jnp.int32(i))
                 prefill_calls = P
             jax.block_until_ready((logits, caches, load))
@@ -117,11 +124,13 @@ class ServingEngine:
         with span("serve.decode"):
             t0 = time.time()
             tok = sample(logits, key)
-            out = [tok]
+            out, decode_load = [tok], []
             for i in range(P, P + gen_len - 1):
                 with span("serve.token"):
-                    logits, caches = self._step(self.params, tok, caches,
-                                                jnp.int32(i))
+                    logits, caches, rows = self._step(self.params, tok,
+                                                      caches, jnp.int32(i))
+                    if rows is not None:
+                        decode_load.append(rows)
                     key = jax.random.fold_in(key, i)
                     tok = sample(logits, key)
                 out.append(tok)
@@ -130,6 +139,8 @@ class ServingEngine:
         with span("serve.fetch"):
             tokens = gen.tolist()
             expert_load = count_expert_load(load[0]) if load else None
+            if decode_load:       # one transfer, not one a decode call
+                count_decode_expert_reads(jnp.stack(decode_load))
         return GenerationResult(
             tokens=tokens, prefill_s=prefill_s, decode_s=decode_s,
             tokens_per_s=B * gen.shape[1] / max(decode_s, 1e-9),
@@ -151,3 +162,13 @@ def count_expert_load(load) -> list:
                           buckets=LOAD_RATIO_BUCKETS).observe(
             float(load.max() / mean))
     return load.tolist()
+
+
+def count_decode_expert_reads(loads) -> None:
+    """Count decode calls' rows per held expert, (calls, expert layers,
+    held experts), in ``METRICS``: ``decode_expert_reads``, the held
+    experts that computed a row (whose weights the call read), and
+    ``decode_expert_slots``, every held expert of every expert layer."""
+    loads = np.asarray(loads)
+    METRICS.counter("decode_expert_reads").inc(int((loads > 0).sum()))
+    METRICS.counter("decode_expert_slots").inc(loads.size)
