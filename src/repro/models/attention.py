@@ -1,4 +1,5 @@
-"""Attention: GQA/MHA and MLA (DeepSeek latent), full + prefill + decode paths.
+"""Attention: GQA/MHA and MLA (DeepSeek latent), full + prefill + decode
+paths, and encoder-decoder cross-attention (full + decode).
 
 Full-sequence attention is *blockwise* (lax.scan over KV blocks with online
 softmax — flash-attention semantics at the XLA level) so that 32k-token
@@ -202,6 +203,41 @@ def apply_attention_decode(p, cfg, x, cache, index):
     o = o.reshape(B, 1, H * hd).astype(dt)
     return o @ p["wo"].astype(dt), {"k": k_c, "v": v_c}
 
+
+def apply_cross_attention_full(p, cfg, x, enc_out):
+    """Decoder queries x (B,Sq,D) over every encoder position of enc_out
+    (B,Se,D), no rope."""
+    B, Sq, _ = x.shape
+    Se = enc_out.shape[1]
+    hd, H, KH = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    dt = x.dtype
+    q = (x @ p["wq"].astype(dt)).reshape(B, Sq, H, hd).transpose(0, 2, 1, 3)
+    k = (enc_out @ p["wk"].astype(dt)).reshape(B, Se, KH, hd)
+    v = (enc_out @ p["wv"].astype(dt)).reshape(B, Se, KH, hd)
+    if KH != H:
+        k = jnp.repeat(k, H // KH, axis=2)
+        v = jnp.repeat(v, H // KH, axis=2)
+    k = k.transpose(0, 2, 1, 3)
+    v = v.transpose(0, 2, 1, 3)
+    qpos = jnp.zeros((Sq,), jnp.int32)
+    kpos = jnp.zeros((Se,), jnp.int32)
+    out = blockwise_attention(q, k, v, qpos, kpos, prefix_len=jnp.int32(1))
+    out = out.transpose(0, 2, 1, 3).reshape(B, Sq, H * hd)
+    return out @ p["wo"].astype(dt)
+
+
+def apply_cross_attention_decode(p, cfg, x, cache):
+    """One decoder token x (B,1,D) over the encoder's K/V cache (B,KH,Se,hd)."""
+    B = x.shape[0]
+    hd, H, KH = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
+    dt = x.dtype
+    kc, vc = cache["k"], cache["v"]
+    q = (x @ p["wq"].astype(dt)).reshape(B, KH, H // KH, hd)
+    s = jnp.einsum("bkgd,bksd->bkgs", q.astype(jnp.float32),
+                   kc.astype(jnp.float32)) * hd ** -0.5
+    w = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bkgs,bksd->bkgd", w.astype(vc.dtype), vc)
+    return o.reshape(B, 1, H * hd).astype(dt) @ p["wo"].astype(dt)
 
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek-V3 multi-head latent attention)

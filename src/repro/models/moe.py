@@ -15,8 +15,9 @@ over ``model``. (The §Perf hillclimb replaces replicated activations + psum
 with sequence-sharded activations + all-to-all dispatch: ``moe_a2a``.)
 
 FSDP-compatible: if expert weights arrive d_model-sharded over ``data``
-(DeepSeek-671B config), they are all-gathered per layer inside the shard_map
-— exactly the FSDP weight-gather pattern.
+(the sharding rules in force map ``embed`` to ``data``: ``FSDP_RULES``),
+they are all-gathered per layer inside the shard_map — exactly the FSDP
+weight-gather pattern.
 
 A layer told that it holds ``experts_held`` experts from ``expert_offset``
 (one chip's share under expert parallelism) routes over all
@@ -25,9 +26,9 @@ every (token, held expert) pair: no capacity drop at any load. Its pairs
 are sorted by expert and one loop runs the row tiles that have rows, each
 expert as many as it needs, in ascending expert order. A tile reads its
 expert's gate, up and down weights in place, by (layer, expert), from the
-stacked params at its matmuls: the expert layer scans of
-``apply_moe_decode`` and ``apply_moe_prefill`` leave the held experts out
-of their ``xs`` and pass the whole stack and the layer index to
+stacked params at its matmuls: the expert layer scan of the model's
+prefill and decode (``transformer._moe_walk``) leaves the held experts out
+of its ``xs`` and passes the whole stack and the layer index to
 ``apply_moe_held_at`` (``apply_moe_held`` and ``apply_moe`` pass one
 layer's experts as a stack of one). So the cost
 follows the routed rows and an expert that got no token is never read
@@ -217,7 +218,14 @@ def _expert_compute_local(x2d, idx2d, w2d, gate, up, down, e0, e_local, cap):
     return y
 
 
-def _apply_moe_a2a(cfg, mesh, x2d, idx2d, w2d, ex):
+def experts_fsdp(mesh, rules) -> bool:
+    """Whether the sharding rules in force shard the experts' d_model
+    (``embed``) dim over ``data``, as ``FSDP_RULES`` do: each shard then
+    all-gathers its experts' weights before it uses them."""
+    return mesh.shape.get("data", 1) > 1 and rules.get("embed") == "data"
+
+
+def _apply_moe_a2a(cfg, mesh, x2d, idx2d, w2d, ex, fsdp: bool):
     """Sequence-sharded EP with all-to-all dispatch (§Perf optimization).
 
     The shard_map boundary keeps the SAME layout as the surrounding layers
@@ -242,8 +250,6 @@ def _apply_moe_a2a(cfg, mesh, x2d, idx2d, w2d, ex):
     c_send = _capacity(t_local, k, tp, cfg.capacity_factor)  # per-dest bucket
     c_comp = _capacity(tp * c_send, 1, e_local, cfg.capacity_factor)
 
-    fsdp = ("data" in mesh.shape and mesh.shape["data"] > 1
-            and cfg.name.startswith("deepseek"))
     gspec = P("model", "data", None) if fsdp else P("model", None, None)
     dspec = P("model", None, "data") if fsdp else P("model", None, None)
 
@@ -378,7 +384,8 @@ def apply_moe(p, cfg, x) -> Tuple[jax.Array, jax.Array]:
     elif (strategy in ("moe_a2a", "moe_a2a_seqshard")
           and (B * S) % (tp * max(1, mesh.shape.get("data", 1)
                                   * mesh.shape.get("pod", 1))) == 0):
-        y = _apply_moe_a2a(cfg, mesh, x2d, idx2d, w2d, ex)
+        y = _apply_moe_a2a(cfg, mesh, x2d, idx2d, w2d, ex,
+                           experts_fsdp(mesh, _rules))
     else:
         e_local = E // tp
         batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
@@ -393,7 +400,7 @@ def apply_moe(p, cfg, x) -> Tuple[jax.Array, jax.Array]:
                 ax[d_axis] = "data"
             return P(*ax)
 
-        fsdp = "data" in mesh.shape and mesh.shape["data"] > 1 and cfg.name.startswith("deepseek")
+        fsdp = experts_fsdp(mesh, _rules)
         gspec = wspec(1) if fsdp else P("model", None, None)
         dspec = wspec(2) if fsdp else P("model", None, None)
 

@@ -1,24 +1,22 @@
 """Batched serving engine: cache-backed prefill + greedy/temperature decode.
 
-Wraps the per-family decode paths (KV cache for attention families,
-O(1) recurrent state for SSM/hybrid) behind one request-batch API. The
-``serve_step`` this engine jits is the same function the ``decode_32k`` /
-``long_500k`` dry-run cells lower at production scale. A prompt of the
-families in ``T.PREFILL_FAMILIES`` fills the caches in one call; the other
-families feed it through the decode step, one call a prompt token.
+Wraps the model's decode step (KV cache for attention families, O(1)
+recurrent state for SSM/hybrid) behind one request-batch API, the same
+for every family. The ``serve_step`` this engine jits is the same
+function the ``decode_32k`` / ``long_500k`` dry-run cells lower at
+production scale. A prompt of the families in ``T.PREFILL_FAMILIES`` fills
+the caches in one call; the other families feed it through the decode
+step, one call a prompt token.
 
 For family moe the prefill call also returns how many prompt rows each
 held expert of each expert layer computed. They come back with the
-logits and are counted in ``METRICS`` (docs/observability.md). A decode
-call of a layer that holds a share of the experts returns its rows per
-held expert too; ``generate`` keeps them on the device and fetches them
-once, with the tokens, to count the held experts each call read.
+logits and are counted in ``METRICS`` (docs/observability.md).
 """
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass, field
-from typing import List, Optional
+from dataclasses import dataclass
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,22 +57,17 @@ class ServingEngine:
         self.max_len = max_len
         self.cache_dtype = cache_dtype
 
-        # (logits, caches, rows per held expert of each expert layer, or
-        # None where no layer holds a share)
-        def decode_step(p, t, c, i):
-            if cfg.family == "moe":
-                return T.apply_moe_decode(p, cfg, t, c, i)
-            return (*T.apply_lm_decode(p, cfg, t, c, i), None)
-
         # named, so that its module reads ``jit_decode_step`` in a trace
+        def decode_step(p, t, c, i):
+            return T.apply_lm_decode(p, cfg, t, c, i)
+
         self._step = jax.jit(decode_step)
         self._prefill = None
-        if cfg.family in T.PREFILL_FAMILIES:
-            fn = (T.apply_moe_prefill if cfg.family == "moe"
-                  else T.apply_lm_prefill)
-
+        if T.has_one_call_prefill(cfg):
+            # (logits, caches, rows per held expert of each expert layer,
+            # or None without expert layers)
             def prefill(p, t, n, c):
-                return fn(p, cfg, t, n, c)
+                return T.apply_lm_prefill(p, cfg, t, n, c)
 
             # module ``jit_prefill``; one program per (batch, max_len): the
             # prompt is padded to ``_prefill_len`` and its length is traced
@@ -103,16 +96,16 @@ class ServingEngine:
                 # padded on the host: a device pad would compile once per P
                 padded = np.zeros((B, self._prefill_len), np.int32)
                 padded[:, :P] = np.asarray(prompts)
-                logits, caches, *load = self._prefill(
+                logits, caches, rows = self._prefill(
                     self.params, padded, np.int32(P), caches)
                 prefill_calls = 1
             else:
-                load = []
+                rows = None
                 for i in range(P):          # prefill via the decode path
-                    logits, caches, _ = self._step(
+                    logits, caches = self._step(
                         self.params, prompts[:, i:i + 1], caches, jnp.int32(i))
                 prefill_calls = P
-            jax.block_until_ready((logits, caches, load))
+            jax.block_until_ready((logits, caches, rows))
             prefill_s = time.time() - t0
         prompt_logits = logits[:, -1]
 
@@ -124,13 +117,11 @@ class ServingEngine:
         with span("serve.decode"):
             t0 = time.time()
             tok = sample(logits, key)
-            out, decode_load = [tok], []
+            out = [tok]
             for i in range(P, P + gen_len - 1):
                 with span("serve.token"):
-                    logits, caches, rows = self._step(self.params, tok,
-                                                      caches, jnp.int32(i))
-                    if rows is not None:
-                        decode_load.append(rows)
+                    logits, caches = self._step(self.params, tok, caches,
+                                                jnp.int32(i))
                     key = jax.random.fold_in(key, i)
                     tok = sample(logits, key)
                 out.append(tok)
@@ -138,9 +129,8 @@ class ServingEngine:
             decode_s = time.time() - t0
         with span("serve.fetch"):
             tokens = gen.tolist()
-            expert_load = count_expert_load(load[0]) if load else None
-            if decode_load:       # one transfer, not one a decode call
-                count_decode_expert_reads(jnp.stack(decode_load))
+            expert_load = (count_expert_load(rows) if rows is not None
+                           else None)
         return GenerationResult(
             tokens=tokens, prefill_s=prefill_s, decode_s=decode_s,
             tokens_per_s=B * gen.shape[1] / max(decode_s, 1e-9),
@@ -163,12 +153,3 @@ def count_expert_load(load) -> list:
             float(load.max() / mean))
     return load.tolist()
 
-
-def count_decode_expert_reads(loads) -> None:
-    """Count decode calls' rows per held expert, (calls, expert layers,
-    held experts), in ``METRICS``: ``decode_expert_reads``, the held
-    experts that computed a row (whose weights the call read), and
-    ``decode_expert_slots``, every held expert of every expert layer."""
-    loads = np.asarray(loads)
-    METRICS.counter("decode_expert_reads").inc(int((loads > 0).sum()))
-    METRICS.counter("decode_expert_slots").inc(loads.size)

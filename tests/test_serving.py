@@ -1,10 +1,19 @@
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax._src.array import ArrayImpl
 
-from repro.configs import get_arch, reduced
-from repro.models import transformer as T
-from repro.serving.engine import ServingEngine
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+
+from bench.tests.test_mla_moe import cut  # noqa: E402
+from repro.configs import get_arch, reduced  # noqa: E402
+from repro.models import transformer as T  # noqa: E402
+from repro.serving import engine as E  # noqa: E402
+from repro.serving.engine import ServingEngine  # noqa: E402
 
 
 @pytest.mark.parametrize("aid", ["stablelm-1.6b", "mamba2-370m",
@@ -72,12 +81,13 @@ def test_prefill_matches_the_decode_loop(aid, length):
     S = T.prefill_len(cfg, MAX_LEN)
     assert S == {"dense": MAX_LEN, "ssm": 48}[cfg.family]
     padded = jnp.zeros((2, S), jnp.int32).at[:, :length].set(prompts)
-    got, got_caches = jax.jit(
+    got, got_caches, rows = jax.jit(
         lambda p, t, n, c: T.apply_lm_prefill(p, cfg, t, n, c))(
         params, padded, jnp.int32(length),
         T.init_caches(cfg, 2, MAX_LEN, jnp.float32))
 
     assert got.shape == want.shape == (2, 1, cfg.padded_vocab)
+    assert rows is None                   # no layer holds experts
     assert rel_gap(got, want) < 1e-4
     names = {"dense": ("k", "v"),
              "ssm": ("state", "conv_x", "conv_B", "conv_C")}[cfg.family]
@@ -146,3 +156,61 @@ def test_generate_refuses_prompts_that_do_not_fit(prompt_len):
     eng = ServingEngine(cfg, params, max_len=32)
     with pytest.raises(ValueError, match="do not fit"):
         eng.generate(jnp.ones((2, prompt_len), jnp.int32), gen_len=4)
+
+
+# the three cells' families at the tests' size: stablelm, mamba2, and
+# DeepSeek-V3's expert-parallel share (4 of 8 experts held)
+FETCH_CASES = {
+    "dense": lambda: reduced(get_arch("stablelm-1.6b").model),
+    "ssm": lambda: reduced(get_arch("mamba2-370m").model),
+    "moe": lambda: cut()[0],
+}
+
+
+@pytest.mark.parametrize("family", list(FETCH_CASES))
+def test_generate_fetches_nothing_per_token(family, monkeypatch):
+    """No decode call's output goes to the host inside ``serve.token``:
+    ``generate`` fetches the tokens (and a prefill's rows per held expert)
+    once, after the decode loop."""
+    cfg = FETCH_CASES[family]()
+    assert cfg.family == family
+    eng = ServingEngine(cfg, T.init_lm(jax.random.PRNGKey(9), cfg),
+                        max_len=24)
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (2, 6), 0,
+                                 cfg.vocab_size)
+    eng.generate(prompts, 3)                   # compiles every program
+
+    in_token, fetched = [False], []
+    value = ArrayImpl._value
+
+    def fetch(self):
+        fetched.append(in_token[0])
+        return value.fget(self)
+
+    asarray = np.asarray
+
+    def np_asarray(a, *args, **kw):
+        if isinstance(a, jax.Array):
+            fetched.append(in_token[0])
+        return asarray(a, *args, **kw)
+
+    class Span(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            super().__init__(name, **kw)
+            self.token = name == "serve.token"
+
+        def __enter__(self):
+            in_token[0] = self.token
+            return super().__enter__()
+
+        def __exit__(self, *exc):
+            in_token[0] = False
+            return super().__exit__(*exc)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(fetch))
+    monkeypatch.setattr(np, "asarray", np_asarray)
+    monkeypatch.setattr(E, "span", Span)
+    out = eng.generate(prompts, 5)
+    assert len(out.tokens[0]) == 5
+    assert (out.expert_load is not None) == (family == "moe")
+    assert fetched and not any(fetched)     # fetches, none per token

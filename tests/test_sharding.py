@@ -5,7 +5,7 @@ from types import SimpleNamespace
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.configs import get_arch
+from repro.configs import ARCH_IDS, get_arch
 from repro.sharding.ctx import logical_to_spec
 from repro.sharding.rules import (DEFAULT_RULES, FSDP_RULES,
                                   batch_logical_axes, cache_logical_axes,
@@ -59,6 +59,27 @@ def test_fsdp_rules_shard_embed_dim():
                            FSDP_RULES) == P("data", "model")
     assert rules_for("deepseek-v3-671b") is FSDP_RULES
     assert rules_for("stablelm-1.6b") is DEFAULT_RULES
+
+
+STRATEGIES = ("baseline", "moe_a2a", "moe_a2a_seqshard", "moe_rs",
+              "dp_zero1", "pure_fsdp")
+
+
+@pytest.mark.parametrize("aid", [a for a in ARCH_IDS
+                                 if get_arch(a).model.num_experts])
+def test_expert_fsdp_follows_the_rules_in_force(aid):
+    """The expert weights are FSDP-gathered exactly where the rules shard
+    their ``embed`` dim over ``data``: for every strategy whose rules put
+    the experts on ``model`` (where the EP paths run), that is DeepSeek-V3
+    (in FSDP_ARCHS) and not OLMoE, and no mesh without a data axis."""
+    from repro.models.moe import experts_fsdp
+    deepseek = get_arch(aid).model.name.startswith("deepseek")
+    for strategy in STRATEGIES:
+        rules = rules_for(aid, strategy)
+        if rules.get("expert") != "model":
+            continue
+        assert experts_fsdp(MESH, rules) == deepseek, strategy
+        assert not experts_fsdp(FakeMesh(shape={"model": 16}), rules)
 
 
 class _K:
