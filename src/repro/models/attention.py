@@ -11,9 +11,15 @@ decode cache; the MLA prefill writes the latent cache (``c_kv``, ``k_rope``)
 and attends one batch row at a time, so its scores stay one row's size.
 
 Decode attends a single new token against a KV cache laid out
-(batch, kv_heads, seq, head_dim) so the sharding resolver prefers
-head-sharding and falls back to split-KV sequence sharding when
-``kv_heads % TP != 0`` (flash-decoding pattern).
+(batch, kv_heads / f, seq, f * head_dim): f heads side by side in a row
+(``kv_pack``), so a row fills a TPU tile's 128 lanes where a head is
+narrower and the cache is neither padded nor laid out position-minor. The
+sharding resolver prefers head-sharding and falls back to split-KV
+sequence sharding when the head axis does not divide TP (flash-decoding
+pattern). A decode call reads the cache where it lies and returns only the
+new position's rows; the layer walk writes every layer's rows at once
+(``write_rows``) into the caches the serving engine's decode step takes
+donated and consumes.
 """
 from __future__ import annotations
 
@@ -27,6 +33,7 @@ from repro.models import layers as L
 from repro.sharding.ctx import shard
 
 KV_BLOCK = 1024
+LANES = 128         # the minor dimension of a TPU tile
 
 
 # ---------------------------------------------------------------------------
@@ -146,62 +153,122 @@ def apply_attention_full(p, cfg, x, positions, prefix_len=None):
 
 def apply_attention_prefill(p, cfg, x, positions, cache):
     """Causal full attention that also writes the rotated k and v of every
-    position of x into the cache (B,KH,S_cache,hd) from position 0: the
+    position of x into the cache (``init_kv_cache``) from position 0: the
     cache ``apply_attention_decode`` leaves after S calls, up to rounding.
 
     Returns (out (B,S,D), new_cache)."""
     q, k, v = _qkv(p, cfg, x, positions)
     out = _attend(p, q, k, v, positions, None)
+    f = kv_pack(cfg)
     k_c = jax.lax.dynamic_update_slice(
-        cache["k"], k.transpose(0, 2, 1, 3).astype(cache["k"].dtype), (0, 0, 0, 0))
+        cache["k"], _pack(k, f).astype(cache["k"].dtype), (0, 0, 0, 0))
     v_c = jax.lax.dynamic_update_slice(
-        cache["v"], v.transpose(0, 2, 1, 3).astype(cache["v"].dtype), (0, 0, 0, 0))
+        cache["v"], _pack(v, f).astype(cache["v"].dtype), (0, 0, 0, 0))
     k_c = shard(k_c, "batch", "kv_heads", "kv_seq", None)
     v_c = shard(v_c, "batch", "kv_heads", "kv_seq", None)
     return out, {"k": k_c, "v": v_c}
 
 
+def kv_pack(cfg) -> int:
+    """f, the kv heads a cache row holds side by side: the most that divide
+    num_kv_heads and fit one tile's lanes (1 for heads of 128 or wider). A
+    narrower head alone in a row leaves the cache padded to the lanes, or
+    laid out position-minor, where a decode call's one-position rows are
+    padded instead."""
+    KH, hd = cfg.num_kv_heads, cfg.head_dim
+    return max(f for f in range(1, KH + 1)
+               if KH % f == 0 and (f == 1 or f * hd <= LANES))
+
+
+def _pack(x, f):
+    """(B,S,KH,hd) -> the cache's layout (B,KH/f,S,f*hd): kv heads j*f to
+    j*f+f-1 side by side in row j."""
+    B, S, KH, hd = x.shape
+    return x.reshape(B, S, KH // f, f * hd).transpose(0, 2, 1, 3)
+
+
 def init_kv_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
+    """Zero K/V caches (batch, kv_heads/f, max_len, f*head_dim), f
+    ``kv_pack(cfg)``: the rotated keys and values of every position."""
+    f, KH, hd = kv_pack(cfg), cfg.num_kv_heads, cfg.head_dim
+    return {
+        "k": jnp.zeros((batch, KH // f, max_len, f * hd), dtype),
+        "v": jnp.zeros((batch, KH // f, max_len, f * hd), dtype),
+    }
+
+
+def init_cross_cache(cfg, batch: int, enc_len: int, dtype=jnp.bfloat16):
+    """Zero encoder K/V caches (batch, kv_heads, enc_len, head_dim), which
+    ``apply_cross_attention_decode`` reads."""
     hd, KH = cfg.head_dim, cfg.num_kv_heads
     return {
-        "k": jnp.zeros((batch, KH, max_len, hd), dtype),
-        "v": jnp.zeros((batch, KH, max_len, hd), dtype),
+        "k": jnp.zeros((batch, KH, enc_len, hd), dtype),
+        "v": jnp.zeros((batch, KH, enc_len, hd), dtype),
     }
 
 
 def apply_attention_decode(p, cfg, x, cache, index):
-    """x: (B,1,D_in); cache k/v: (B,KH,S,hd); index: scalar current position.
+    """x: (B,1,D_in); cache k/v as ``init_kv_cache`` lays them out, read
+    where they lie; index: scalar current position.
 
-    Returns (out (B,1,D), new_cache).
+    Attends over the cache's positions below ``index`` and the new token's
+    own key and value, which the cache does not hold yet, in f32. A query
+    meets a packed row with zeros outside its own head's lanes, and keeps
+    those lanes of the weighted sum of value rows. Returns (out (B,1,D),
+    the new position's rows {"k", "v"} (B,KH/f,1,f*hd) in the cache's
+    dtype): the walk writes them at ``index`` (``write_rows``).
     """
     B = x.shape[0]
     hd, H, KH = cfg.head_dim, cfg.num_heads, cfg.num_kv_heads
-    dt = x.dtype
+    f, G = kv_pack(cfg), H // KH
+    dt, f32 = x.dtype, jnp.float32
     q = (x @ p["wq"].astype(dt)).reshape(B, 1, H, hd)
     k = (x @ p["wk"].astype(dt)).reshape(B, 1, KH, hd)
     v = (x @ p["wv"].astype(dt)).reshape(B, 1, KH, hd)
     pos = jnp.full((B, 1), index, jnp.int32)
     q = L.apply_rope(q, pos, cfg.rope_theta)
     k = L.apply_rope(k, pos, cfg.rope_theta)
+    rows = {"k": _pack(k, f).astype(cache["k"].dtype),
+            "v": _pack(v, f).astype(cache["v"].dtype)}
+    k_c = shard(cache["k"], "batch", "kv_heads", "kv_seq", None)
+    v_c = shard(cache["v"], "batch", "kv_heads", "kv_seq", None)
 
-    k_c = jax.lax.dynamic_update_slice(
-        cache["k"], k.transpose(0, 2, 1, 3).astype(cache["k"].dtype), (0, 0, index, 0))
-    v_c = jax.lax.dynamic_update_slice(
-        cache["v"], v.transpose(0, 2, 1, 3).astype(cache["v"].dtype), (0, 0, index, 0))
-    k_c = shard(k_c, "batch", "kv_heads", "kv_seq", None)
-    v_c = shard(v_c, "batch", "kv_heads", "kv_seq", None)
-
-    G = H // KH
-    qg = q.reshape(B, KH, G, hd)                       # (B,KH,G,hd)
-    s = jnp.einsum("bkgd,bksd->bkgs", qg.astype(jnp.float32),
-                   k_c.astype(jnp.float32)) * hd ** -0.5
-    S = k_c.shape[2]
-    valid = jnp.arange(S)[None, None, None, :] <= index
-    s = jnp.where(valid, s, -1e30)
-    w = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgs,bksd->bkgd", w.astype(v_c.dtype), v_c)
+    # lane l of a row is head l // hd of its f: (f, 1, f*hd)
+    own = jnp.arange(f * hd) // hd == jnp.arange(f)[:, None, None]
+    qg = q.reshape(B, KH // f, f, G, hd).astype(f32)
+    qg = jnp.where(own, jnp.tile(qg, f), 0.0)            # (B,KH/f,f,G,f*hd)
+    k_new, v_new = (r[:, :, None].astype(f32) for r in (rows["k"], rows["v"]))
+    s = jnp.sum(qg[..., None, :] * k_c[:, :, None, None].astype(f32), -1)
+    s_new = jnp.sum(qg * k_new, -1)
+    w, w_new = _softmax_with_new(s * hd ** -0.5, s_new * hd ** -0.5, index)
+    o = jnp.sum(w[..., None] * v_c[:, :, None, None].astype(f32), -2)
+    o = o + w_new[..., None] * v_new                     # (B,KH/f,f,G,f*hd)
+    o = jnp.where(own, o, 0.0).reshape(B, KH // f, f, G, f, hd).sum(-2)
     o = o.reshape(B, 1, H * hd).astype(dt)
-    return o @ p["wo"].astype(dt), {"k": k_c, "v": v_c}
+    return o @ p["wo"].astype(dt), rows
+
+
+def _softmax_with_new(s, s_new, index):
+    """The masked softmax of a decode step over the cache's scores s
+    (..., S) at the positions below ``index`` and the new token's score
+    s_new (...): the weights of the cache's positions (..., S) and of the
+    new token (...)."""
+    s = jnp.where(jnp.arange(s.shape[-1]) < index, s, -1e30)
+    m = jnp.maximum(jnp.max(s, axis=-1), s_new)
+    e, e_new = jnp.exp(s - m[..., None]), jnp.exp(s_new - m)
+    total = jnp.sum(e, axis=-1) + e_new
+    return e / total[..., None], e_new / total
+
+
+def write_rows(cache, rows, index):
+    """Write a decode step's new rows into attention caches at position
+    ``index``: one ``dynamic_update_slice`` a tensor, each tensor's
+    sequence axis its second last (GQA (..., B, KH/f, S, f*hd), MLA (...,
+    B, S, c)), so a stack of layers takes every layer's row at once."""
+    return jax.tree.map(
+        lambda c, r: jax.lax.dynamic_update_slice_in_dim(c, r, index,
+                                                         c.ndim - 2),
+        cache, rows)
 
 
 def apply_cross_attention_full(p, cfg, x, enc_out):
@@ -373,36 +440,39 @@ def init_mla_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
 
 
 def apply_mla_decode(p, cfg, x, cache, index):
-    """Absorbed-matmul MLA decode: attends in latent space, O(kv_lora) cache."""
+    """Absorbed-matmul MLA decode: attends in latent space over the cache
+    (``c_kv`` (B,S,kvr), ``k_rope`` (B,S,rope), read where it lies) at the
+    positions below ``index`` and the new token's own latent and rope key.
+    Returns (out (B,1,D), the new position's rows {"c_kv", "k_rope"}
+    (B,1,kvr), (B,1,rope) in the cache's dtype): the walk writes them at
+    ``index`` (``write_rows``)."""
     B = x.shape[0]
     H = cfg.num_heads
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     dt = x.dtype
     pos = jnp.full((B, 1), index, jnp.int32)
     q_nope, q_rope, c_kv_new, k_rope_new = _mla_qkv(p, cfg, x, pos)
-
-    c_kv = jax.lax.dynamic_update_slice(
-        cache["c_kv"], c_kv_new.astype(cache["c_kv"].dtype), (0, index, 0))
-    k_rope = jax.lax.dynamic_update_slice(
-        cache["k_rope"], k_rope_new[:, :, 0, :].astype(cache["k_rope"].dtype),
-        (0, index, 0))
-    c_kv = shard(c_kv, "batch", "kv_seq", None)
-    k_rope = shard(k_rope, "batch", "kv_seq", None)
+    rows = {"c_kv": c_kv_new.astype(cache["c_kv"].dtype),
+            "k_rope": k_rope_new[:, :, 0, :].astype(cache["k_rope"].dtype)}
+    c_kv = shard(cache["c_kv"], "batch", "kv_seq", None)
+    k_rope = shard(cache["k_rope"], "batch", "kv_seq", None)
 
     kvb = p["wkv_b"].astype(dt).reshape(cfg.kv_lora_rank, H, nope + vd)
     w_uk, w_uv = kvb[..., :nope], kvb[..., nope:]
     # absorb W_uk into the query -> latent-space scores
     q_lat = jnp.einsum("bshn,chn->bshc", q_nope, w_uk)          # (B,1,H,kvr)
-    s = jnp.einsum("bshc,btc->bhst", q_lat.astype(jnp.float32),
-                   c_kv.astype(jnp.float32))
-    s += jnp.einsum("bshr,btr->bhst", q_rope.astype(jnp.float32),
-                    k_rope.astype(jnp.float32))
-    s *= mla_scale(cfg)
-    Smax = c_kv.shape[1]
-    valid = jnp.arange(Smax)[None, None, None, :] <= index
-    s = jnp.where(valid, s, -1e30)
-    w = jax.nn.softmax(s, axis=-1)
+    q_lat, q_rope = q_lat.astype(jnp.float32), q_rope.astype(jnp.float32)
+    c_new = rows["c_kv"].astype(jnp.float32)
+    s = jnp.einsum("bshc,btc->bhst", q_lat, c_kv.astype(jnp.float32))
+    s += jnp.einsum("bshr,btr->bhst", q_rope, k_rope.astype(jnp.float32))
+    s_new = jnp.einsum("bshc,bsc->bhs", q_lat, c_new)
+    s_new += jnp.einsum("bshr,bsr->bhs", q_rope,
+                        rows["k_rope"].astype(jnp.float32))
+    w, w_new = _softmax_with_new(s * mla_scale(cfg), s_new * mla_scale(cfg),
+                                 index)
     ctx = jnp.einsum("bhst,btc->bshc", w.astype(c_kv.dtype), c_kv)  # latent ctx
+    ctx = ctx + jnp.einsum("bhs,bsc->bshc", w_new.astype(c_kv.dtype),
+                           rows["c_kv"])
     o = jnp.einsum("bshc,chn->bshn", ctx.astype(dt), w_uv)          # (B,1,H,vd)
     o = o.reshape(B, 1, H * vd)
-    return o @ p["wo"].astype(dt), {"c_kv": c_kv, "k_rope": k_rope}
+    return o @ p["wo"].astype(dt), rows

@@ -191,8 +191,17 @@ def init_caches(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
         return c
     if fam == "encdec":
         return {"self": _stack_cache(kv, n), "cross": _stack_cache(
-            lambda: A.init_kv_cache(cfg, batch, cfg.enc_seq, dtype), n)}
+            lambda: A.init_cross_cache(cfg, batch, cfg.enc_seq, dtype), n)}
     raise ValueError(fam)
+
+
+def split_states(caches):
+    """(attention caches, recurrent states) of a tree ``init_caches``
+    builds. A decode call writes an attention cache one position at a
+    time (or, encdec's ``cross``, only reads it) and rewrites an entry
+    whose layers hold an SSM state (``state``, ``conv_*``) whole."""
+    states = {k: c for k, c in caches.items() if "state" in c}
+    return {k: c for k, c in caches.items() if k not in states}, states
 
 
 # ---------------------------------------------------------------------------
@@ -204,8 +213,10 @@ class _Mode:
     """``full``: the whole sequence at ``positions`` (the first
     ``prefix_len`` attend both ways), no caches. ``prefill``: a prompt
     right-padded past ``length``, at ``positions``, writing the caches.
-    ``decode``: one token at position ``index``, reading and writing them.
-    Each mixer returns (y, new cache), the cache None in full mode."""
+    ``decode``: one token at position ``index``, reading them. Each mixer
+    returns (y, its cache's update): None in full mode, the new cache in
+    prefill and for a recurrent state in decode, and an attention mixer's
+    new rows in decode, which ``attn_caches`` writes after the walk."""
     cfg: Any
     kind: str
     positions: Any = None
@@ -226,6 +237,16 @@ class _Mode:
             return fn(p, cfg, x, self.positions, cache)
         fn = A.apply_mla_decode if mla else A.apply_attention_decode
         return fn(p, cfg, x, cache, self.index)
+
+    def attn_caches(self, caches, out):
+        """A stack of attention caches after the walk, from its layer scan's
+        outputs: prefill's are the new caches; decode's are each layer's
+        rows at ``index``, written here into the stack (one
+        ``dynamic_update_slice`` a tensor, in place where the caller donates
+        the caches)."""
+        if self.kind != "decode":
+            return out
+        return A.write_rows(caches, out, self.index)
 
     def ssm(self, p, x, cache):
         if self.kind == "full":
@@ -325,7 +346,7 @@ def _dec_block(mode, lp, h, cache):
 # ---------------------------------------------------------------------------
 
 def _scan(mode, block, h, params, caches, remat="none"):
-    """``block(mode, layer params, h, cache) -> (h, new cache)`` over
+    """``block(mode, layer params, h, cache) -> (h, cache update)`` over
     stacked layers: in full mode over the params alone, under ``remat``;
     else over (params, caches)."""
     if mode.kind == "full":
@@ -353,6 +374,8 @@ def _moe_walk(mode, params, h, caches, remat):
         h, new["dense_layers"] = _scan(mode, _mlp_block, h,
                                        params["dense_layers"],
                                        caches.get("dense_layers"), remat)
+        new["dense_layers"] = mode.attn_caches(caches.get("dense_layers"),
+                                               new["dense_layers"])
     if mode.kind == "full":
         def step(carry, lp):
             hh, ax = carry
@@ -374,8 +397,9 @@ def _moe_walk(mode, params, h, caches, remat):
                                      valid)
         return hh, (cache, rows if held else None)
     n_moe = cfg.num_layers - cfg.first_k_dense
-    h, (new["layers"], rows) = jax.lax.scan(
+    h, (out, rows) = jax.lax.scan(
         step, h, (layers, caches["layers"], jnp.arange(n_moe)))
+    new["layers"] = mode.attn_caches(caches["layers"], out)
     return h, new, rows
 
 
@@ -386,11 +410,14 @@ def _walk(mode, params, h, caches, remat="none", enc_out=None):
     loss in full mode, or its rows per held expert of each expert layer
     (expert layers, experts held) on the held path; else None."""
     fam = mode.cfg.family
-    if fam in ("dense", "vlm", "ssm"):
-        block = _ssm_block if fam == "ssm" else _mlp_block
-        h, new = _scan(mode, block, h, params["layers"], caches.get("layers"),
-                       remat)
+    if fam == "ssm":
+        h, new = _scan(mode, _ssm_block, h, params["layers"],
+                       caches.get("layers"), remat)
         return h, {"layers": new}, None
+    if fam in ("dense", "vlm"):
+        h, new = _scan(mode, _mlp_block, h, params["layers"],
+                       caches.get("layers"), remat)
+        return h, {"layers": mode.attn_caches(caches.get("layers"), new)}, None
     if fam == "moe":
         return _moe_walk(mode, params, h, caches, remat)
     if fam == "hybrid":
@@ -405,7 +432,8 @@ def _walk(mode, params, h, caches, remat="none", enc_out=None):
         h, (groups, shared) = _scan(
             mode, group, h, params["groups"],
             (caches.get("groups"), caches.get("shared")))
-        new = {"groups": groups, "shared": shared}
+        new = {"groups": groups,
+               "shared": mode.attn_caches(caches.get("shared"), shared)}
         if "leftover" in params:
             h, new["leftover"] = _scan(mode, _ssm_block, h, params["leftover"],
                                        caches.get("leftover"), remat)
@@ -415,7 +443,8 @@ def _walk(mode, params, h, caches, remat="none", enc_out=None):
             return _dec_block(mode, lp, hh, cache or (None, enc_out))
         h, new = _scan(mode, dec, h, params["dec_layers"],
                        (caches.get("self"), caches.get("cross")), remat)
-        return h, {"self": new, "cross": caches.get("cross")}, None
+        return h, {"self": mode.attn_caches(caches.get("self"), new),
+                   "cross": caches.get("cross")}, None
     raise ValueError(fam)
 
 

@@ -11,6 +11,12 @@ step, one call a prompt token.
 For family moe the prefill call also returns how many prompt rows each
 held expert of each expert layer computed. They come back with the
 logits and are counted in ``METRICS`` (docs/observability.md).
+
+The prefill call consumes the caches it is handed (donated). A decode
+call consumes the attention caches: it writes only the new position of
+each, in place, and returns the caches the next call takes. It rewrites a
+recurrent state whole into a new buffer, and does not take it donated: a
+donated state would first be copied.
 """
 from __future__ import annotations
 
@@ -57,11 +63,13 @@ class ServingEngine:
         self.max_len = max_len
         self.cache_dtype = cache_dtype
 
-        # named, so that its module reads ``jit_decode_step`` in a trace
-        def decode_step(p, t, c, i):
-            return T.apply_lm_decode(p, cfg, t, c, i)
+        # named, so that its module reads ``jit_decode_step`` in a trace;
+        # kv: the attention caches, donated; states: the recurrent states
+        # (``T.split_states``)
+        def decode_step(p, t, kv, states, i):
+            return T.apply_lm_decode(p, cfg, t, {**kv, **states}, i)
 
-        self._step = jax.jit(decode_step)
+        self._step = jax.jit(decode_step, donate_argnums=(2,))
         self._prefill = None
         if T.has_one_call_prefill(cfg):
             # (logits, caches, rows per held expert of each expert layer,
@@ -73,6 +81,13 @@ class ServingEngine:
             # prompt is padded to ``_prefill_len`` and its length is traced
             self._prefill = jax.jit(prefill, donate_argnums=(3,))
             self._prefill_len = T.prefill_len(cfg, max_len)
+
+    def decode(self, tokens, caches, index: int):
+        """One ``jit_decode_step`` call: tokens (B,1) at position ``index``
+        -> (logits (B,1,V), the new caches). It consumes the attention
+        caches it is handed."""
+        kv, states = T.split_states(caches)
+        return self._step(self.params, tokens, kv, states, jnp.int32(index))
 
     def generate(self, prompts: jax.Array, gen_len: int,
                  temperature: float = 0.0, seed: int = 0) -> GenerationResult:
@@ -102,8 +117,8 @@ class ServingEngine:
             else:
                 rows = None
                 for i in range(P):          # prefill via the decode path
-                    logits, caches = self._step(
-                        self.params, prompts[:, i:i + 1], caches, jnp.int32(i))
+                    logits, caches = self.decode(prompts[:, i:i + 1],
+                                                 caches, i)
                 prefill_calls = P
             jax.block_until_ready((logits, caches, rows))
             prefill_s = time.time() - t0
@@ -120,8 +135,7 @@ class ServingEngine:
             out = [tok]
             for i in range(P, P + gen_len - 1):
                 with span("serve.token"):
-                    logits, caches = self._step(self.params, tok, caches,
-                                                jnp.int32(i))
+                    logits, caches = self.decode(tok, caches, i)
                     key = jax.random.fold_in(key, i)
                     tok = sample(logits, key)
                 out.append(tok)

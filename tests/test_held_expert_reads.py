@@ -128,7 +128,8 @@ def test_no_expert_layer_stack_is_sliced_out(call):
     i32 = jax.ShapeDtypeStruct((), jnp.int32)
     if call == "decode":
         lowered = eng._step.lower(
-            params, jax.ShapeDtypeStruct((2, 1), jnp.int32), caches, i32)
+            params, jax.ShapeDtypeStruct((2, 1), jnp.int32),
+            *T.split_states(caches), i32)
     else:
         lowered = eng._prefill.lower(
             params, jax.ShapeDtypeStruct((2, eng._prefill_len), jnp.int32),

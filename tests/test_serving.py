@@ -214,3 +214,59 @@ def test_generate_fetches_nothing_per_token(family, monkeypatch):
     assert len(out.tokens[0]) == 5
     assert (out.expert_load is not None) == (family == "moe")
     assert fetched and not any(fetched)     # fetches, none per token
+
+
+# one architecture of each family whose decode writes an attention cache:
+# GQA, MLA over held experts (DeepSeek-V3's share), zamba2's shared
+# attention beside SSM states, whisper's self-attention beside its
+# read-only cross cache
+DONATED_CASES = {
+    "dense": lambda: f32_model("stablelm-1.6b")[0],
+    "moe_mla": lambda: cut(param_dtype="float32", compute_dtype="float32")[0],
+    "hybrid": lambda: f32_model("zamba2-1.2b")[0],
+    "encdec": lambda: f32_model("whisper-large-v3")[0],
+}
+
+
+@pytest.mark.parametrize("family", list(DONATED_CASES))
+def test_donated_decode_matches_the_undonated_loop(family):
+    """``generate``, whose decode calls take the attention caches donated
+    and write only the new position, serves the tokens and prompt logits
+    of an undonated ``T.apply_lm_decode`` loop; each call consumes the
+    attention caches it is handed, not the recurrent states, and returns
+    the logits and caches of the undonated step."""
+    cfg = DONATED_CASES[family]()
+    params = T.init_lm(jax.random.PRNGKey(4), cfg)
+    B, P, G, S = 2, 5, 4, 16
+    eng = ServingEngine(cfg, params, max_len=S)
+    prompts = jax.random.randint(jax.random.PRNGKey(5), (B, P), 0,
+                                 cfg.vocab_size)
+    got = eng.generate(prompts, gen_len=G)
+
+    step = jax.jit(lambda p, t, c, i: T.apply_lm_decode(p, cfg, t, c, i))
+    caches = T.init_caches(cfg, B, S, jnp.float32)
+    for i in range(P):
+        logits, caches = step(params, prompts[:, i:i + 1], caches,
+                              jnp.int32(i))
+    assert rel_gap(got.prompt_logits, logits[:, -1]) < 1e-4
+    want = [jnp.argmax(logits[:, -1], -1)[:, None]]
+    for i in range(P, P + G - 1):
+        logits, caches = step(params, want[-1], caches, jnp.int32(i))
+        want.append(jnp.argmax(logits[:, -1], -1)[:, None])
+    assert got.tokens == jnp.concatenate(want, axis=1).tolist()
+
+    # one call from the same caches, donated and not
+    tok = want[-1]
+    handed = jax.tree.map(jnp.copy, caches)
+    kv, states = T.split_states(handed)
+    assert kv and (bool(states) == (family == "hybrid"))
+    lg, new = eng.decode(tok, handed, P + G - 1)
+    assert all(x.is_deleted() for x in jax.tree.leaves(kv))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(states))
+    ref_lg, ref = step(params, tok, caches, jnp.int32(P + G - 1))
+    assert rel_gap(lg, ref_lg) < 1e-5
+    assert jax.tree.structure(new) == jax.tree.structure(ref)
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(ref)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-5 * max(
+            1.0, float(jnp.max(jnp.abs(b))))

@@ -1,6 +1,7 @@
 """Compiles for a described TPU v5e, with no chip attached: the Pallas
 kernels at real widths, and the one-chip check's train step and the
-serving engine's prefill against the chip's memory. A compile that passes is not a chip run; it catches what
+serving engine's prefill and decode against the chip's memory. A compile
+that passes is not a chip run; it catches what
 the chip's compiler refuses (unaligned blocks, unsupported primitives,
 programs that do not fit) at no chip time.
 
@@ -165,10 +166,17 @@ def _deepseek_compiled(one_chip, call):
         tokens = _sds((batch, eng._prefill_len), jnp.int32, one_chip)
         c = eng._prefill.lower(params, tokens, i32, caches).compile()
     else:
-        tokens = _sds((batch, 1), jnp.int32, one_chip)
-        c = eng._step.lower(params, tokens, caches, i32).compile()
+        c = _decode_compiled(eng, params, caches, batch, one_chip)
     _DEEPSEEK_COMPILED[call] = c
     return c
+
+
+def _decode_compiled(eng, params, caches, batch, one_chip):
+    """The engine's ``jit_decode_step`` for one token a request."""
+    from repro.models import transformer as T
+    kv, states = T.split_states(caches)
+    return eng._step.lower(params, _sds((batch, 1), jnp.int32, one_chip), kv,
+                           states, _sds((), jnp.int32, one_chip)).compile()
 
 
 @pytest.mark.parametrize("call", ["prefill", "decode"])
@@ -177,17 +185,67 @@ def test_deepseek_share_serving_fits_one_chip(one_chip, call):
     16,160-row vocabulary slice) at published widths, at the serve cell's
     batch 8 and cache length 2,112: the engine's ``jit_prefill`` and
     ``jit_decode_step`` with their weights, f32 latent caches and
-    temporaries fit in one chip's HBM, and the prefill takes its caches
-    donated."""
+    temporaries fit in one chip's HBM, and both take the latent caches
+    donated: c_kv and k_rope of 7 layers, 272.5 MB."""
     m = _deepseek_compiled(one_chip, call).memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     print(call, "argument", m.argument_size_in_bytes, "output",
           m.output_size_in_bytes, "temp", m.temp_size_in_bytes, "alias",
           m.alias_size_in_bytes, "total", total)
-    if call == "prefill":
-        assert m.alias_size_in_bytes > 0
+    cfg = _deepseek_share()
+    latent = (cfg.num_layers * 8 * 2112 * (cfg.kv_lora_rank + cfg.qk_rope_dim)
+              * 4)
+    assert latent == 272_498_688
+    assert m.alias_size_in_bytes >= latent
     assert total < V5E_HBM_BYTES, total
+
+
+def test_stablelm_decode_writes_the_new_position_in_place(one_chip):
+    """The engine's ``jit_decode_step`` for stablelm-1.6b at the prompt
+    cell's batch 8 and cache length 272, f32 caches: it takes both K/V
+    stacks donated and writes each layer's new row into them in place,
+    one dynamic-update-slice a tensor after the layer scan, with
+    temporaries under one layer's K+V. No layer's K/V or stack's is copied
+    or rewritten, in the cache's layout (two 64-wide heads a 128-lane row,
+    f32[24,8,16,272,128]) or in a head's (f32[1,8,32,272,64],
+    f32[8,32,272,64]), as when each call relaid every layer for its einsum
+    and wrote it whole into a fresh stack (1.21 GB)."""
+    from repro.configs import get_arch
+    from repro.models import transformer as T
+    from repro.serving.engine import ServingEngine
+    cfg, batch, max_len = get_arch("stablelm-1.6b").model, 8, 272
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: T.init_lm(jax.random.PRNGKey(0), cfg)))
+    caches = on_chip(jax.eval_shape(
+        lambda: T.init_caches(cfg, batch, max_len, jnp.float32)))
+    c = _decode_compiled(ServingEngine(cfg, None, max_len=max_len), params,
+                         caches, batch, one_chip)
+    m = c.memory_analysis()
+    print("decode argument", m.argument_size_in_bytes, "output",
+          m.output_size_in_bytes, "temp", m.temp_size_in_bytes, "alias",
+          m.alias_size_in_bytes)
+    L, KH, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+    stack = caches["layers"]["k"].shape
+    assert stack == (L, batch, KH // 2, max_len, 2 * hd) == (24, 8, 16, 272,
+                                                             128)
+    stack_bytes = L * batch * KH * max_len * hd * 4
+    assert 2 * stack_bytes == 855_638_016
+    assert m.alias_size_in_bytes >= 2 * stack_bytes
+    assert m.temp_size_in_bytes < 2 * stack_bytes // L
+    dims = lambda shape: "f32[" + ",".join(map(str, shape)) + "]{"
+    shapes = [dims(s) for s in (stack, (1,) + stack[1:], stack[1:],
+                                (L, batch, KH, max_len, hd),
+                                (1, batch, KH, max_len, hd),
+                                (batch, KH, max_len, hd))]
+    writes = [line.strip() for line in c.as_text().splitlines()
+              if any(" = " + s in line for s in shapes)
+              and any(op in line for op in (" copy(", " dynamic-update-slice("))]
+    assert len(writes) == 2, writes[:4]
+    for line, name in zip(sorted(writes), ("k", "v")):
+        assert "dynamic-update-slice(%kv__layers____" + name + "__" in line, line
 
 
 def test_deepseek_decode_reads_held_experts_in_place(one_chip):
